@@ -6,8 +6,9 @@ policy; we sweep the compute-region size and report the measured overhead
 curves + min region @10% -- the shape of the paper's result reproduced at
 chip granularity with actual timings.
 
-Run in a fresh process (device count must be set before jax init):
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+Run in a fresh process on host-CPU devices (device count must be set
+before jax init); ``benchmarks.run`` spawns it this way:
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python -m benchmarks.jax_barriers
 """
 
@@ -18,9 +19,9 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_axis_mesh, shard_map
 from repro.sync import available_policies, get_policy
 
 REGION_SIZES = [1, 2, 4, 8, 16, 32, 64]  # matmul repetitions between barriers
@@ -39,7 +40,7 @@ def _make_step(mesh, strategy: str, region: int):
         return x
 
     return jax.jit(
-        shard_map(body, mesh=mesh, in_specs=(P("x"), P()), out_specs=P("x"))
+        jax.shard_map(body, mesh=mesh, in_specs=(P("x"), P()), out_specs=P("x"))
     )
 
 
@@ -55,9 +56,8 @@ def _time(fn, *args, reps=5) -> float:
 def run(verbose: bool = True) -> Dict:
     n = jax.device_count()
     if n < 2:
-        print("[jax_barriers] needs >=2 devices; skipping")
-        return {}
-    mesh = make_axis_mesh((n,), ("x",))
+        raise SystemExit(f"[jax_barriers] needs >= 2 devices, found {n}")
+    mesh = jax.make_mesh((n,), ("x",), axis_types=(AxisType.Auto,))
     x = jnp.ones((n * 8, DIM), jnp.float32)
     a = jnp.eye(DIM, dtype=jnp.float32) * 0.99
 
@@ -70,7 +70,7 @@ def run(verbose: bool = True) -> Dict:
                 for _ in range(region):
                     x = jnp.tanh(x @ a)
             return x
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("x"), P()), out_specs=P("x")))
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("x"), P()), out_specs=P("x")))
 
     t_full = _time(compute_only(x, a), x, a)
     unit = t_full / (N_BARRIERS * max(REGION_SIZES))

@@ -27,10 +27,13 @@ Table-1/Fig-5/chain/work-queue sweeps and their scaling variants dispatch
 through the batched fleet engine
 (``repro.core.scu.engine.simulate_fleet``); per-config numbers are
 bit-exact against sequential runs.  The chip-level barrier timing
-benchmark needs its own process with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and is invoked as a
-subprocess (device count is locked at jax init); its failure propagates to
-this process's exit code so CI actually gates on it.
+benchmark measures 8 host-CPU devices by design, so it runs as a child
+process with ``JAX_PLATFORMS=cpu`` and
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the device count is
+locked at jax init); on a TPU host the child therefore never competes for
+the chip, and this parent refuses to spawn it once it has touched a JAX
+backend itself.  The child's failure propagates to this process's exit
+code so CI actually gates on it.
 
 ``--only`` restricts the run to a comma-separated subset of sections (see
 ``BENCHES``; unknown names exit nonzero) for CI and local iteration.
@@ -337,7 +340,16 @@ def _run_preemption(args):
     ("jax_barriers_ok",),
 )
 def _run_jax_barriers(args):
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+
+        if xla_bridge.backends_are_initialized():
+            raise RuntimeError(
+                "benchmarks.run initialized a JAX backend before spawning "
+                "jax_barriers; the parent must leave every device to the child"
+            )
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = "src"
     r = subprocess.run(

@@ -1,28 +1,17 @@
-"""jax availability + version-portability shims.
-
-The repo targets current jax APIs (``jax.shard_map``, ``jax.sharding.
-AxisType``, ``pltpu.CompilerParams``); the pinned container jax may predate
-them.  Every version-sensitive construct is funneled through this module so
-the rest of the code reads as if it were written against one jax.
+"""The jax availability gate.
 
 The simulator core and the compiled trace path are pure numpy and must run
 where jax is absent (or deliberately disabled with ``REPRO_NO_JAX=1``, the
-CI fast lane): :data:`HAS_JAX` is the single gate, and the shims below raise
-a clear ImportError only when actually called without jax.
+CI fast lane): :data:`HAS_JAX` is the single gate, and :func:`require_jax`
+raises a clear ImportError only when a jax feature is actually called
+without it.  Everything else is written against the installed jax.
 """
 
 from __future__ import annotations
 
 import os
 
-__all__ = [
-    "HAS_JAX",
-    "CompilerParams",
-    "axis_size",
-    "make_axis_mesh",
-    "require_jax",
-    "shard_map",
-]
+__all__ = ["HAS_JAX", "require_jax"]
 
 if os.environ.get("REPRO_NO_JAX"):
     jax = None
@@ -44,45 +33,3 @@ def require_jax(feature: str = "this feature"):
             "and compiled-trace paths work without it"
         )
     return jax
-
-
-def axis_size(axis: str) -> int:
-    """Static size of a named mesh axis from inside shard_map/pmap.
-
-    ``jax.lax.axis_size`` where it exists; otherwise ``psum(1, axis)``,
-    which constant-folds to a concrete int under a bound axis.
-    """
-    j = require_jax("axis_size")
-    fn = getattr(j.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return j.lax.psum(1, axis)
-
-
-if HAS_JAX:
-    from jax.experimental.pallas import tpu as pltpu
-
-    # pltpu.TPUCompilerParams was renamed to pltpu.CompilerParams in newer jax.
-    CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-else:
-    CompilerParams = None
-
-
-def make_axis_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    j = require_jax("make_axis_mesh")
-    axis_type = getattr(j.sharding, "AxisType", None)
-    if axis_type is not None:
-        return j.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return j.make_mesh(shape, axes)
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map``, falling back to the experimental spelling."""
-    j = require_jax("shard_map")
-    sm = getattr(j, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)

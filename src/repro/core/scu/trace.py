@@ -802,6 +802,7 @@ class TraceRunMonitor:
 # --------------------------------------------------------------------------
 
 _X_ACTIVE, _X_STALL, _X_DONE = 0, 1, 2
+_I32_MAX = int(np.iinfo(np.int32).max)
 
 
 def _pack_tables(programs: Sequence[TraceProgram]):
@@ -858,38 +859,50 @@ def run_traces_xp(
     tab_np, addrs_np = _pack_tables(programs)
     n, length, _ = tab_np.shape
     is_np = xp is np
+    # All state is int32 under both namespaces (jax without x64 has no
+    # int64).  A counter grows by at most 2 + the largest poll instruction
+    # count per cycle, so bounding that by the cycle cap bounds every count.
+    per_cycle = 2 + int(tab_np[:, :, 7:9].max(initial=0))
+    if max_cycles * per_cycle > _I32_MAX or np.abs(tab_np).max() > _I32_MAX:
+        raise ValueError(
+            f"max_cycles={max_cycles} (or a table operand) overflows the "
+            "executor's int32 state"
+        )
 
-    tab = xp.asarray(tab_np)
+    tab = xp.asarray(tab_np.astype(np.int32))
     op_k = tab[:, :, 0]
     rep_n = tab[:, :, 1]
     a0, a1, a2 = tab[:, :, 2], tab[:, :, 3], tab[:, :, 4]
     a3, a4, a5, a6 = tab[:, :, 5], tab[:, :, 6], tab[:, :, 7], tab[:, :, 8]
-    addr_bank = xp.asarray((addrs_np >> 2) % n_banks)
+    addr_bank = xp.asarray(((addrs_np >> 2) % n_banks).astype(np.int32))
     lanes = xp.arange(n)
 
     state = {
-        "pc": xp.zeros(n, dtype=xp.int64),
-        "rep": xp.zeros(n, dtype=xp.int64),
-        "R": xp.zeros(n, dtype=xp.int64),
-        "st": xp.zeros(n, dtype=xp.int64),
-        "busy": xp.zeros(n, dtype=xp.int64),
-        "pend": xp.full((n,), -1, dtype=xp.int64),  # row idx of pending op
-        "pdata": xp.zeros(n, dtype=xp.int64),  # latched store data
-        "tcdm": xp.zeros(len(addrs_np), dtype=xp.int64),
-        "rr": xp.zeros(n_banks, dtype=xp.int64),
-        "cnt": xp.zeros((len(_COUNTERS), n), dtype=xp.int64),
-        "conflicts": xp.zeros((), dtype=xp.int64),
-        "fin": xp.full((n,), -1, dtype=xp.int64),
-        "cycle": xp.zeros((), dtype=xp.int64),
+        "pc": xp.zeros(n, dtype=xp.int32),
+        "rep": xp.zeros(n, dtype=xp.int32),
+        "R": xp.zeros(n, dtype=xp.int32),
+        "st": xp.zeros(n, dtype=xp.int32),
+        "busy": xp.zeros(n, dtype=xp.int32),
+        "pend": xp.full((n,), -1, dtype=xp.int32),  # row idx of pending op
+        "pdata": xp.zeros(n, dtype=xp.int32),  # latched store data
+        "tcdm": xp.zeros(len(addrs_np), dtype=xp.int32),
+        "rr": xp.zeros(n_banks, dtype=xp.int32),
+        "cnt": xp.zeros((len(_COUNTERS), n), dtype=xp.int32),
+        "conflicts": xp.zeros((), dtype=xp.int32),
+        "fin": xp.full((n,), -1, dtype=xp.int32),
+        "cycle": xp.zeros((), dtype=xp.int32),
     }
 
     def _set(arr, idx, val, mask):
+        # masked scatter: only the masked lanes write.  Unmasked lanes may
+        # share an index with a writer (an idle lane's pending row defaults
+        # to row 0), so they must not write back the old value -- with
+        # duplicate indices that could clobber the real write.
         if is_np:
             out = arr.copy()
-            out[idx] = np.where(mask, val, out[idx])
+            out[idx[mask]] = np.broadcast_to(val, idx.shape)[mask]
             return out
-        sel = xp.where(mask, val, arr[idx])
-        return arr.at[idx].set(sel)
+        return arr.at[xp.where(mask, idx, arr.shape[0])].set(val, mode="drop")
 
     def _add(arr, idx, val, mask):
         # per-lane counter bump: arr[idx[lane], lane] += val[lane] where mask
@@ -949,7 +962,7 @@ def run_traces_xp(
         new_pc = xp.where(data & (r == 0), pc + 1, pc)
         new_rep = xp.where(data, r, rep)
         cnt = s["cnt"]
-        cnt = _add(cnt, 5 * xp.ones(n, dtype=xp.int64), 1, data)  # instructions
+        cnt = _add(cnt, 5 * xp.ones(n, dtype=xp.int32), 1, data)  # instructions
         # COMPUTE: busy = max(0, c - 1), stay ACTIVE
         c0 = xp.take_along_axis(a0, pc[:, None], axis=1)[:, 0]
         comp = data & (row_k == T_COMPUTE)
@@ -1003,11 +1016,11 @@ def run_traces_xp(
         rr = _set(s["rr"], xp.arange(n_banks), (wlane + 1) % n, has)
         # effects
         cnt = s["cnt"]
-        cnt = _add(cnt, 6 * xp.ones(n, dtype=xp.int64), 1, win)  # tcdm
+        cnt = _add(cnt, 6 * xp.ones(n, dtype=xp.int32), 1, win)  # tcdm
         val = s["tcdm"][aidx]
         is_poll = win & (r_kind == T_POLL)
         is_tas = win & (m_kind == _MK_TAS)
-        cnt = _add(cnt, 7 * xp.ones(n, dtype=xp.int64), 1, is_tas)  # tas
+        cnt = _add(cnt, 7 * xp.ones(n, dtype=xp.int32), 1, is_tas)  # tas
         # tas (Mem or Poll) writes -1 and pays the 3-cycle latency
         tcdm = _set(s["tcdm"], aidx, -1, is_tas)
         base = xp.where(is_tas, tas_cycles - 1, 0)
@@ -1020,7 +1033,7 @@ def run_traces_xp(
         busy = s["busy"]
         busy = xp.where(hit, base + hit_c, busy)
         busy = xp.where(miss, base + miss_c, busy)
-        cnt = _add(cnt, 5 * xp.ones(n, dtype=xp.int64),
+        cnt = _add(cnt, 5 * xp.ones(n, dtype=xp.int32),
                    xp.where(hit, hit_i, miss_i), is_poll)
         R = xp.where(hit, val, s["R"])
         # plain Mem
@@ -1048,11 +1061,11 @@ def run_traces_xp(
         stall = st == _X_STALL
         cnt = s["cnt"]
         inc = xp.stack([
-            clocked.astype(xp.int64),  # active
-            act.astype(xp.int64),  # comp
-            stall.astype(xp.int64),  # wait
-            xp.zeros(n, dtype=xp.int64),  # gated
-            stall.astype(xp.int64),  # stall
+            clocked.astype(xp.int32),  # active
+            act.astype(xp.int32),  # comp
+            stall.astype(xp.int32),  # wait
+            xp.zeros(n, dtype=xp.int32),  # gated
+            stall.astype(xp.int32),  # stall
         ])
         if is_np:
             cnt = cnt.copy()
@@ -1076,7 +1089,7 @@ def run_traces_xp(
         s["busy"] = xp.where(counting, busy - 1, busy)
         reissue = advancing & (pend >= 0)
         s["st"] = xp.where(reissue, _X_STALL, st)
-        s["cnt"] = _add(s["cnt"], 5 * xp.ones(n, dtype=xp.int64), 1, reissue)
+        s["cnt"] = _add(s["cnt"], 5 * xp.ones(n, dtype=xp.int32), 1, reissue)
         s["fetch"] = advancing & (pend < 0)
         # decode until every fetching lane reached a data op or halted
         if is_np:
@@ -1102,16 +1115,10 @@ def run_traces_xp(
         s = account(s)
         return s
 
-    state["ctr"] = xp.full((n, length), -1, dtype=xp.int64)
+    state["ctr"] = xp.full((n, length), -1, dtype=xp.int32)
 
     if is_np:
-        while True:
-            if bool(np.all(state["st"] == _X_DONE)):
-                break
-            if int(state["cycle"]) >= max_cycles:
-                raise RuntimeError(
-                    f"traced run did not finish within {max_cycles} cycles"
-                )
+        while not np.all(state["st"] == _X_DONE) and state["cycle"] < max_cycles:
             state["fetch"] = np.zeros(n, dtype=bool)
             state = cycle_step(state)
     else:
@@ -1126,6 +1133,8 @@ def run_traces_xp(
             return cycle_step(s)
 
         state = jax.lax.while_loop(cond, body, state)
+    if not bool((state["st"] == _X_DONE).all()):
+        raise RuntimeError(f"traced run did not finish within {max_cycles} cycles")
 
     counters = {
         name: np.asarray(state["cnt"][i])

@@ -1,30 +1,20 @@
-"""Jit-ready wrapper: Pallas flash attention on TPU, flash-vjp ref elsewhere.
+"""Model-layout wrapper for the Pallas flash-attention forward kernel.
 
-``flash_attention(q, k, v)`` takes the models' (b, s, h, d) layout, runs the
-Pallas kernel when a TPU backend is present (or ``interpret=True`` is
-forced), and otherwise falls back to the numerically identical pure-JAX
-flash core (which also provides the backward pass -- the Pallas backward
-kernel is future work; on TPU the forward kernel is wrapped in
-``jax.custom_vjp`` with the flash-recompute backward from
-:mod:`repro.models.layers.flash_core`).
+``flash_attention(q, k, v)`` takes the models' (b, s, h, d) layout and always
+runs the Pallas kernel; ``interpret=True`` runs it in interpret mode (the
+CPU test path).  The numerically identical pure-JAX flash core, which also
+provides the backward pass, is
+:func:`repro.models.layers.flash_core.flash_attention_core`; the plain
+oracle is :func:`repro.kernels.flash_attention.ref.attention_ref`.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from repro.models.layers.flash_core import flash_attention_core
 from .kernel import flash_attention_fwd
 
 __all__ = ["flash_attention"]
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def flash_attention(
@@ -35,24 +25,15 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    b, sq, h, d = q.shape
-    kvh = k.shape[2]
-    use_pallas = interpret if interpret is not None else _on_tpu()
-    if use_pallas:
-        out = flash_attention_fwd(
-            q.transpose(0, 2, 1, 3),
-            k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            causal=causal,
-            block_q=block_q,
-            block_k=block_k,
-            interpret=bool(interpret),
-        )
-        return out.transpose(0, 2, 1, 3)
-    g = h // kvh
-    out = flash_attention_core(
-        q.reshape(b, sq, kvh, g, d), k, v, causal, block_q, block_k, 0
+    out = flash_attention_fwd(
+        q.transpose(0, 2, 1, 3),
+        k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3),
+        causal=causal,
+        block_q=block_q,
+        block_k=block_k,
+        interpret=interpret,
     )
-    return out.reshape(b, sq, h, d)
+    return out.transpose(0, 2, 1, 3)

@@ -13,34 +13,35 @@ The head-state scratch (P x N f32, e.g. 64x128 = 32 KiB) stays resident in
 VMEM for the whole sequence -- the TPU-native counterpart of the SSD
 algorithm's "states never leave SRAM between chunks" property on GPUs.
 
+Layout is head-major so every block's last two dims are tile-aligned or
+full: x/y tiles are ``(Q, P)``; ``dt`` arrives twice, as a ``(Q, 1)``
+column (to scale rows) and a ``(1, Q)`` row (to build the segment sums
+without an in-kernel transpose); ``A`` is read per head from SMEM.  The
+cumulative sums are masked row/column reductions, not ``cumsum``.
+
 Grid: (batch, heads, num_chunks), chunk axis innermost/sequential.
 Single-group (g=1) B/C layout, matching the mamba2-1.3b config.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
-
 __all__ = ["ssd_scan_fwd"]
 
 
 def _kernel(
-    x_ref,  # (1, Q, 1, P)   dt-unweighted input tile
-    dt_ref,  # (1, Q, 1)
-    a_ref,  # (1,)           A (negative) for this head
+    a_ref,  # SMEM (h,)     A (negative), one per head
+    x_ref,  # (1, 1, Q, P)  dt-unweighted input tile
+    dtc_ref,  # (1, 1, Q, 1)  dt as a column
+    dtr_ref,  # (1, 1, 1, Q)  dt as a row
     b_ref,  # (1, Q, N)
     c_ref,  # (1, Q, N)
-    y_ref,  # (1, Q, 1, P)
+    y_ref,  # (1, 1, Q, P)
     state_scr,  # VMEM (P, N) f32
-    *,
-    num_chunks: int,
 ):
     ci = pl.program_id(2)
 
@@ -48,51 +49,49 @@ def _kernel(
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)  # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)  # (Q,)
-    A = a_ref[0].astype(jnp.float32)  # scalar
+    A = a_ref[pl.program_id(1)]
+    x = x_ref[0, 0].astype(jnp.float32)  # (Q, P)
+    dA_col = dtc_ref[0, 0].astype(jnp.float32) * A  # (Q, 1)
+    dA_row = dtr_ref[0, 0].astype(jnp.float32) * A  # (1, Q)
     B = b_ref[0].astype(jnp.float32)  # (Q, N)
     C = c_ref[0].astype(jnp.float32)  # (Q, N)
-
-    xb = x * dt[:, None]  # dt-weighted input
-    dA = dt * A  # (Q,)
-    dA_cum = jnp.cumsum(dA)  # (Q,)
-
-    # within-chunk decay kernel: L[i, j] = exp(sum_{j<k<=i} dA_k), j <= i
-    diff = dA_cum[:, None] - dA_cum[None, :] + dA[None, :] * 0.0
     Q = x.shape[0]
+
     ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(jj <= ii, jnp.exp(diff), 0.0)
+    lower = jj <= ii
+    # inclusive within-chunk cumsum of dA, as a column and as a row
+    cum_col = jnp.sum(jnp.where(lower, dA_row, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(ii <= jj, dA_col, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(dA_row, axis=1, keepdims=True)  # (1, 1)
+
+    # within-chunk decay kernel: L[i, j] = exp(sum_{j<k<=i} dA_k), j <= i
+    L = jnp.where(lower, jnp.exp(cum_col - cum_row), 0.0)
+    xb = x * dtc_ref[0, 0].astype(jnp.float32)  # dt-weighted input
 
     # diagonal: (C B^T * L) @ xb
     scores = jax.lax.dot_general(
         C, B, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Q, Q)
-    y_diag = jax.lax.dot_general(
-        scores * L, xb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (Q, P)
+    y_diag = jnp.dot(scores * L, xb, preferred_element_type=jnp.float32)  # (Q, P)
 
     # off-diagonal: C @ state_prev^T with in-chunk decay
     state_prev = state_scr[...]  # (P, N)
     y_off = jax.lax.dot_general(
         C, state_prev, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * jnp.exp(dA_cum)[:, None]  # (Q, P)
+    ) * jnp.exp(cum_col)  # (Q, P)
 
-    y_ref[0, :, 0, :] = (y_diag + y_off).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
-    # state update: state = decay_chunk * state_prev + B^T (decay_states * xb)
-    decay_states = jnp.exp(dA_cum[-1] - dA_cum)  # (Q,)
-    new_contrib = jax.lax.dot_general(
-        xb * decay_states[:, None], B, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (P, N)
-    state_scr[...] = state_prev * jnp.exp(dA_cum[-1]) + new_contrib
+    # state update: state = decay_chunk * state_prev + (decay_states * xb)^T B
+    xd = xb * jnp.exp(total - cum_col)  # (Q, P)
+    new_contrib = jnp.dot(xd.T, B, preferred_element_type=jnp.float32)  # (P, N)
+    state_scr[...] = state_prev * jnp.exp(total) + new_contrib
 
 
 def ssd_scan_fwd(
-    x: jnp.ndarray,  # (b, s, h, p)
-    dt: jnp.ndarray,  # (b, s, h)  positive
+    x: jnp.ndarray,  # (b, h, s, p)
+    dt: jnp.ndarray,  # (b, h, s)  positive
     A: jnp.ndarray,  # (h,) negative
     B: jnp.ndarray,  # (b, s, n)  (single group)
     C: jnp.ndarray,  # (b, s, n)
@@ -100,29 +99,29 @@ def ssd_scan_fwd(
     chunk: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    b, s, h, p = x.shape
+    """Head-major SSD scan.  Returns y: (b, h, s, p)."""
+    b, h, s, p = x.shape
     n = B.shape[-1]
     chunk = min(chunk, s)
     assert s % chunk == 0
     nc = s // chunk
 
-    kernel = functools.partial(_kernel, num_chunks=nc)
-    grid = (b, h, nc)
     return pl.pallas_call(
-        kernel,
-        grid=grid,
+        _kernel,
+        grid=(b, h, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, 0, ci)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
         ],
-        out_specs=pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
+        out_specs=pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, dt, A, B, C)
+    )(A.astype(jnp.float32), x, dt[..., None], dt[:, :, None, :], B, C)

@@ -1,21 +1,19 @@
-"""Jit wrapper for the SSD scan: Pallas on TPU, chunked-jnp elsewhere."""
+"""Model-layout wrapper for the SSD scan kernel."""
 
 from __future__ import annotations
 
-import jax
-
-from repro.models.layers.ssm import ssd_chunked
 from .kernel import ssd_scan_fwd
 
 __all__ = ["ssd_scan"]
 
 
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 256, interpret: bool | None = None):
-    """x: (b,s,h,p); dt: (b,s,h); A: (h,); B, C: (b,s,n)."""
-    use_pallas = interpret if interpret is not None else (
-        jax.default_backend() == "tpu"
+def ssd_scan(x, dt, A, B, C, *, chunk: int = 256, interpret: bool = False):
+    """x: (b,s,h,p); dt: (b,s,h); A: (h,); B, C: (b,s,n).  Returns (b,s,h,p).
+
+    Always the Pallas kernel; ``interpret=True`` runs it in interpret mode.
+    The jnp oracle is :func:`repro.kernels.ssd_scan.ref.ssd_scan_ref`."""
+    y = ssd_scan_fwd(
+        x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), A, B, C,
+        chunk=chunk, interpret=interpret,
     )
-    if use_pallas:
-        return ssd_scan_fwd(x, dt, A, B, C, chunk=chunk, interpret=bool(interpret))
-    y, _ = ssd_chunked(x, dt, A, B[:, :, None, :], C[:, :, None, :], chunk=chunk)
-    return y
+    return y.transpose(0, 2, 1, 3)

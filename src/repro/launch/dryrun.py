@@ -16,12 +16,18 @@ compiles it for the production mesh, and records:
 
 into ``artifacts/dryrun/<mesh>/<arch>__<shape>[__tag].json`` for the
 roofline analysis (benchmarks/roofline.py, EXPERIMENTS.md §Roofline).
+
+The 512 devices are forced host-CPU devices, and the backend is pinned to
+the CPU (``JAX_PLATFORMS=cpu``): on a TPU host jax would otherwise take the
+chip as its default backend.  The costs recorded are therefore those of
+CPU-backend HLO, not of a TPU compile.
 """
 
-# The VERY FIRST lines, before ANY other import (jax locks the device count
-# on first initialization):
+# The VERY FIRST lines, before ANY other import (jax locks the platform and
+# the device count on first initialization):
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")

@@ -19,17 +19,21 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro.compat import make_axis_mesh
+__all__ = ["make_production_mesh", "make_host_mesh", "make_launcher_mesh", "mesh_num_chips"]
 
-__all__ = ["make_production_mesh", "make_host_mesh", "mesh_num_chips"]
+
+def _auto_mesh(shape, axes) -> Mesh:
+    # the sharding rules here are written for Auto axes (jax.make_mesh
+    # defaults to Explicit)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_axis_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: Optional[int] = None) -> Mesh:
@@ -38,8 +42,16 @@ def make_host_mesh(data: int = 2, model: int = 2, pod: Optional[int] = None) -> 
     want = data * model * (pod or 1)
     assert n >= want, f"need {want} devices, have {n}"
     if pod:
-        return make_axis_mesh((pod, data, model), ("pod", "data", "model"))
-    return make_axis_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def make_launcher_mesh() -> Mesh:
+    """The launchers' mesh over every visible device: a model axis of 2 once
+    there are 4 or more devices (so 4 chips give (data=2, model=2))."""
+    n = len(jax.devices())
+    model = 2 if n >= 4 else 1
+    return make_host_mesh(data=n // model, model=model)
 
 
 def mesh_num_chips(mesh: Mesh) -> int:

@@ -15,6 +15,7 @@ import argparse
 
 def main() -> None:
     from repro.configs.base import sync_policy_choices
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -30,9 +31,10 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=100)
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.configs.registry import get_config, get_smoke_config
-    from repro.launch.mesh import make_host_mesh, make_production_mesh
+    from repro.launch.mesh import make_launcher_mesh, make_production_mesh
     from repro.train.data import SyntheticLM
     from repro.train.loop import TrainerConfig, train
     from repro.train.optimizer import OptConfig
@@ -40,11 +42,7 @@ def main() -> None:
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.mesh == "host":
-        import jax
-
-        n = len(jax.devices())
-        model = 2 if n >= 4 else 1
-        mesh = make_host_mesh(data=n // model, model=model)
+        mesh = make_launcher_mesh()
     else:
         mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
 

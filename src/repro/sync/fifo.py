@@ -37,8 +37,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 from repro.core.scu.engine import Compute, Scu
 from repro.core.scu.primitives import DEFAULT_COSTS
 from repro.sync.api import PolicyDef, register_policy
@@ -282,7 +280,7 @@ def fifo_chip_barrier(arrive: jnp.ndarray, axis: str) -> jnp.ndarray:
     holds zero).  2(n-1) pairwise sends, no all-to-all -- the chip analogue
     of the simulator's per-link event queues, exact for any group size.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return arrive
     idx = jax.lax.axis_index(axis)

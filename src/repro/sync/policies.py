@@ -30,7 +30,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -123,7 +122,7 @@ def contribution_vector(arrive: jnp.ndarray, axis: str) -> jnp.ndarray:
     from); combining two vectors with ``maximum`` is a union because each
     slot only ever carries one device's non-negative arrival count.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     vec = jnp.zeros((n,) + arrive.shape, arrive.dtype)
     return vec.at[idx].set(arrive)
@@ -138,7 +137,7 @@ def tas_chip_barrier(arrive: jnp.ndarray, axis: str) -> jnp.ndarray:
     released count is the sum of the exchanged contributions -- exact for any
     group size, with no oracle correction.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     vec = contribution_vector(arrive, axis)
     shift = 1
     while shift < n:
@@ -156,7 +155,7 @@ def sw_chip_barrier(arrive: jnp.ndarray, axis: str) -> jnp.ndarray:
     are a dependency chain, like the spin-lock's serialized acquire order.
     The count is the sum of every token received, exact for any group size.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
     total = arrive
     token = arrive

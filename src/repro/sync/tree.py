@@ -35,8 +35,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 from repro.core.scu.engine import Compute, Mem, Poll, Scu
 from repro.core.scu.primitives import DEFAULT_COSTS, sw_mutex_section
 from repro.sync.api import PolicyDef, register_policy
@@ -212,7 +210,7 @@ def tree_chip_barrier(arrive: jnp.ndarray, axis: str) -> jnp.ndarray:
     XOR partner holds the disjoint sibling block, so adding the exchanged
     value is exact -- the count derives entirely from the exchanged values.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n & (n - 1):
         # butterfly pairing needs a power-of-two group; dissemination is the
         # log-depth exchange that stays exact for any group size

@@ -58,11 +58,9 @@ def test_hlo_analyzer_dot_flops_formula():
 def test_hlo_analyzer_sees_collectives():
     if jax.device_count() < 2:
         pytest.skip("needs >= 2 devices")
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-    from repro.compat import make_axis_mesh
-
-    mesh = make_axis_mesh((2,), ("x",))
+    mesh = jax.make_mesh((2,), ("x",), axis_types=(AxisType.Auto,))
     sh = NamedSharding(mesh, P(None, "x"))
     rep = NamedSharding(mesh, P(None, None))
     x = jax.device_put(jnp.ones((64, 64), jnp.float32), sh)

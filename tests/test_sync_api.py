@@ -8,9 +8,9 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_axis_mesh, shard_map
 from repro.core.scu import SCU, Cluster, Compute, run_barrier_bench
 from repro.kernels.scu_barrier.ops import ref_barrier_count
 from repro.sync import (
@@ -132,7 +132,7 @@ def test_legacy_strategy_wrappers_warn_and_forward():
     from repro.core.sync.strategies import opt_state_specs, shape_gradients
 
     policy = get_policy("scu")
-    mesh = make_axis_mesh((jax.device_count(),), ("x",))
+    mesh = jax.make_mesh((jax.device_count(),), ("x",), axis_types=(AxisType.Auto,))
     shape = jax.ShapeDtypeStruct((8,), jnp.float32)
     with pytest.warns(DeprecationWarning, match="deprecated"):
         got = opt_state_specs("scu", {"w": shape}, mesh)
@@ -222,12 +222,12 @@ def test_chip_barrier_matches_psum_oracle(name):
     n = min(4, jax.device_count())
     if n < 2:
         pytest.skip("needs >= 2 devices")
-    mesh = make_axis_mesh((n,), ("x",))
+    mesh = jax.make_mesh((n,), ("x",), axis_types=(AxisType.Auto,))
     arrive = jnp.ones((n,), jnp.float32)
 
     @jax.jit
     def run(a):
-        return shard_map(
+        return jax.shard_map(
             lambda v: (policy.chip_barrier(v, "x"), ref_barrier_count(v, "x")),
             mesh=mesh,
             in_specs=P("x"),
@@ -316,7 +316,7 @@ def _toy_grads(seed=0):
 def test_extension_shape_gradients_matches_scu(name):
     if jax.device_count() < 4:
         pytest.skip("needs 4 host devices")
-    mesh = make_axis_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     grads = _toy_grads()
     shaped = {}
     for n in ("scu", name):
@@ -338,7 +338,7 @@ def test_extension_shape_gradients_matches_scu(name):
 def test_extension_opt_state_specs_match_scu(name):
     if jax.device_count() < 4:
         pytest.skip("needs 4 host devices")
-    mesh = make_axis_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), _toy_grads()
     )

@@ -228,6 +228,42 @@ def test_run_traces_jax_matches_numpy():
     assert got["tcdm"] == ref["tcdm"]
 
 
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+def test_executors_match_engine_on_table1_sw_barrier(xp_name):
+    """The Table 1 ``sw`` barrier spins on a tas lock that every core polls:
+    only the granted lane may write the lock word, even when idle lanes'
+    pending rows alias the same address."""
+    if xp_name == "jax" and not HAS_JAX:
+        pytest.skip("jax unavailable")
+    from repro.core.scu.trace import run_traces_jax
+
+    run = run_traces_xp if xp_name == "numpy" else run_traces_jax
+    ref = prep_barrier_bench("sw", 8, sfr=0, iters=4, compiled=True).run_sequential().stats
+    fb = prep_barrier_bench("sw", 8, sfr=0, iters=4, compiled=True)
+    res = run(fb.config.programs, n_banks=fb.config.cluster.n_banks)
+    assert res["cycles"] == ref.cycles
+    assert res["bank_conflicts"] == ref.bank_conflicts
+    for name in _COUNTERS:
+        assert res["counters"][name].tolist() == [getattr(c, name) for c in ref.cores]
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+def test_executors_raise_when_cut_at_max_cycles(xp_name):
+    """Neither executor returns partial counters for an unfinished run."""
+    if xp_name == "jax" and not HAS_JAX:
+        pytest.skip("jax unavailable")
+    from repro.core.scu.trace import run_traces_jax
+
+    run = run_traces_xp if xp_name == "numpy" else run_traces_jax
+    with pytest.raises(RuntimeError, match="did not finish within 20 cycles"):
+        run(_tcdm_traces(4), n_banks=8, max_cycles=20)
+
+
+def test_executor_rejects_a_cycle_cap_beyond_int32():
+    with pytest.raises(ValueError, match="int32"):
+        run_traces_xp(_tcdm_traces(2), n_banks=4, max_cycles=2**31)
+
+
 def test_compiled_fleet_row_is_jumping():
     """The >=5x headline mechanism: under fastforward with all-trace
     cursors the run monitor must actually collapse periodic spans (tree
