@@ -14,6 +14,8 @@ of wall clock, and the trace semantics they would exercise are identical to
 the 8-core runs that do cover them.
 """
 
+import time
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -257,6 +259,60 @@ def test_executors_raise_when_cut_at_max_cycles(xp_name):
     run = run_traces_xp if xp_name == "numpy" else run_traces_jax
     with pytest.raises(RuntimeError, match="did not finish within 20 cycles"):
         run(_tcdm_traces(4), n_banks=8, max_cycles=20)
+
+
+_PHASES = ["scu.pack", "scu.stage", "scu.loop", "scu.wait", "scu.readback"]
+
+
+def _spanned_runs(xp_name, calls=1):
+    """Run the executor ``calls`` times; the spans and counters of the calls."""
+    if xp_name == "jax" and not HAS_JAX:
+        pytest.skip("jax unavailable")
+    from repro import obs
+    from repro.core.scu.trace import run_traces_jax
+
+    run = run_traces_xp if xp_name == "numpy" else run_traces_jax
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        run(_tcdm_traces(4), n_banks=8)
+    return obs.events(t0, time.perf_counter())
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+def test_executor_records_one_run_of_five_phases(xp_name):
+    ev = _spanned_runs(xp_name, calls=2)
+    roots = [e for e in ev if e.name == "scu.run"]
+    assert len(roots) == 2
+    for root in roots:
+        assert root.parent is None and root.root == root.id
+        kids = [e for e in ev if e.parent == root.id and e.n is None]
+        assert [e.name for e in kids] == _PHASES
+        assert all(e.root == root.id for e in ev if e.start_ns >= root.start_ns
+                   and e.end_ns <= root.end_ns)
+        for a, b in zip(kids, kids[1:]):
+            assert a.end_ns <= b.start_ns
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+def test_executor_phases_cover_the_run(xp_name):
+    ev = _spanned_runs(xp_name)
+    (root,) = [e for e in ev if e.name == "scu.run"]
+    kids = [e for e in ev if e.parent == root.id and e.n is None]
+    covered = sum(e.end_ns - e.start_ns for e in kids)
+    assert covered >= 0.95 * (root.end_ns - root.start_ns)
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+def test_executor_counts_each_trace_of_its_loop(xp_name):
+    """One ``scu.loop_traces`` per jax call, under that call's ``scu.loop``;
+    the numpy loop is never traced."""
+    ev = _spanned_runs(xp_name, calls=2)
+    traces = [e for e in ev if e.name == "scu.loop_traces"]
+    loops = [e.id for e in ev if e.name == "scu.loop"]
+    if xp_name == "numpy":
+        assert traces == []
+    else:
+        assert [(e.n, e.parent) for e in traces] == [(1, loops[0]), (1, loops[1])]
 
 
 def test_executor_rejects_a_cycle_cap_beyond_int32():
