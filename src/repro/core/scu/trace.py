@@ -44,8 +44,11 @@ mutable) policy state.  Re-running a config means re-lowering or
 
 from __future__ import annotations
 
+import functools
 import os
-from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Set, Tuple
+from typing import (
+    Any, Callable, Dict, Iterable, List, NamedTuple, Optional, Sequence, Set, Tuple,
+)
 
 import numpy as np
 
@@ -832,22 +835,342 @@ def _pack_tables(programs: Sequence[TraceProgram]):
     return tab, np.array(addrs, dtype=np.int64)
 
 
-def _scoped(name: str, on: bool):
-    """Decorate an executor phase to run under ``jax.named_scope(name)``
-    when ``on``: a label on the HLO it traces, and nothing else."""
+class _Tables(NamedTuple):
+    """The executor's read-only inputs, passed to every phase: the packed
+    table's columns (lane x row), each TCDM address's bank, the lane index
+    and the static sizes.  ``xp`` is the array namespace they live in."""
+
+    xp: Any
+    op_k: Any
+    rep_n: Any
+    a0: Any
+    a1: Any
+    a2: Any
+    a3: Any
+    a4: Any
+    a5: Any
+    a6: Any
+    addr_bank: Any
+    lanes: Any
+    n: int
+    n_banks: int
+    tas_cycles: int
+
+
+def _scoped(name: str):
+    """Decorate an executor phase ``fn(t, s)`` to run under
+    ``jax.named_scope(name)`` on the jax path: a label on the HLO it
+    traces, and nothing else."""
 
     def wrap(fn):
-        if not on:
-            return fn
-        import jax
+        @functools.wraps(fn)
+        def scoped(t, s):
+            if t.xp is np:
+                return fn(t, s)
+            import jax
 
-        def scoped(s):
             with jax.named_scope(name):
-                return fn(s)
+                return fn(t, s)
 
         return scoped
 
     return wrap
+
+
+def _set(t, arr, idx, val, mask):
+    # masked scatter: only the masked lanes write.  Unmasked lanes may share
+    # an index with a writer (an idle lane's pending row defaults to row 0),
+    # so they must not write back the old value -- with duplicate indices
+    # that could clobber the real write.
+    if t.xp is np:
+        out = arr.copy()
+        out[idx[mask]] = np.broadcast_to(val, idx.shape)[mask]
+        return out
+    return arr.at[t.xp.where(mask, idx, arr.shape[0])].set(val, mode="drop")
+
+
+def _add(t, arr, idx, val, mask):
+    # per-lane counter bump: arr[idx[lane], lane] += val[lane] where mask
+    if t.xp is np:
+        out = arr.copy()
+        v = val if np.isscalar(val) else val[mask]
+        np.add.at(out, (idx[mask], t.lanes[mask]), v)
+        return out
+    return arr.at[idx, t.lanes].add(t.xp.where(mask, val, 0))
+
+
+@_scoped("scu.decode")
+def _decode_step(t, s):
+    """Resolve one control row for every lane that needs a fetch."""
+    xp, lanes = t.xp, t.lanes
+    pc, rep, R, st = s["pc"], s["rep"], s["R"], s["st"]
+    row_k = xp.take_along_axis(t.op_k, pc[:, None], axis=1)[:, 0]
+    fetching = s["fetch"] & (st == _X_ACTIVE)
+    is_ctrl = fetching & (row_k >= T_JMP)
+    r0 = xp.take_along_axis(t.a0, pc[:, None], axis=1)[:, 0]
+    r1 = xp.take_along_axis(t.a1, pc[:, None], axis=1)[:, 0]
+    # JMP
+    jmp = is_ctrl & (row_k == T_JMP)
+    new_pc = xp.where(jmp, r0, pc)
+    # BR: taken when R == imm
+    br = is_ctrl & (row_k == T_BR)
+    new_pc = xp.where(br, xp.where(R == r0, r1, pc + 1), new_pc)
+    # LOOP: per-(lane, row) counters; -1 = not armed yet
+    lp = is_ctrl & (row_k == T_LOOP)
+    ctr = s["ctr"]
+    cur = xp.take_along_axis(ctr, pc[:, None], axis=1)[:, 0]
+    cur = xp.where(cur < 0, r1, cur)
+    take = lp & (cur > 0)
+    new_pc = xp.where(lp, xp.where(cur > 0, r0, pc + 1), new_pc)
+    new_ctr_val = xp.where(take, cur - 1, -1)
+    if xp is np:
+        ctr = ctr.copy()
+        ctr[lanes[lp], pc[lp]] = new_ctr_val[lp]
+    else:
+        ctr = ctr.at[lanes, pc].set(
+            xp.where(lp, new_ctr_val, ctr[lanes, pc])
+        )
+    # HALT
+    halt = is_ctrl & (row_k == T_HALT)
+    st = xp.where(halt, _X_DONE, st)
+    fin = xp.where(halt & (s["fin"] < 0), s["cycle"], s["fin"])
+    s = dict(s)
+    s.update(pc=new_pc, st=st, fin=fin, ctr=ctr)
+    s["fetch"] = fetching & is_ctrl & ~halt
+    return s
+
+
+@_scoped("scu.issue")
+def _issue_data(t, s):
+    """Lanes whose pc sits on a data row: issue it (instr, busy/stall)."""
+    xp, n = t.xp, t.n
+    pc, rep = s["pc"], s["rep"]
+    fetch = s["fetch"]
+    row_k = xp.take_along_axis(t.op_k, pc[:, None], axis=1)[:, 0]
+    data = fetch & (row_k <= T_SCU)
+    rn = xp.take_along_axis(t.rep_n, pc[:, None], axis=1)[:, 0]
+    r = xp.where(rep > 0, rep, rn) - 1
+    new_pc = xp.where(data & (r == 0), pc + 1, pc)
+    new_rep = xp.where(data, r, rep)
+    cnt = s["cnt"]
+    cnt = _add(t, cnt, 5 * xp.ones(n, dtype=xp.int32), 1, data)  # instructions
+    # COMPUTE: busy = max(0, c - 1), stay ACTIVE
+    c0 = xp.take_along_axis(t.a0, pc[:, None], axis=1)[:, 0]
+    comp = data & (row_k == T_COMPUTE)
+    busy = xp.where(comp, xp.maximum(c0 - 1, 0), s["busy"])
+    # MEM / POLL: pend at the issuing row, STALL; delta stores latch now
+    memp = data & ((row_k == T_MEM) | (row_k == T_POLL))
+    st = xp.where(memp, _X_STALL, s["st"])
+    pend = xp.where(memp, pc, s["pend"])
+    d_imm = xp.take_along_axis(t.a2, pc[:, None], axis=1)[:, 0]
+    d_flag = xp.take_along_axis(t.a3, pc[:, None], axis=1)[:, 0]
+    pdata = xp.where(
+        data & (row_k == T_MEM),
+        xp.where(d_flag == 1, s["R"] + d_imm, d_imm),
+        s["pdata"],
+    )
+    s = dict(s)
+    s.update(pc=new_pc, rep=new_rep, busy=busy, st=st, pend=pend,
+             pdata=pdata, cnt=cnt)
+    s["fetch"] = s["fetch"] & ~data
+    return s
+
+
+@_scoped("scu.grant")
+def _grant(t, s):
+    """Per-bank round-robin arbitration + transaction effects."""
+    xp, lanes, n, n_banks, tas_cycles = t.xp, t.lanes, t.n, t.n_banks, t.tas_cycles
+    st, pend = s["st"], s["pend"]
+    req = st == _X_STALL
+    p_row = xp.where(req, pend, 0)
+    r_kind = t.op_k[lanes, p_row]  # T_MEM / T_POLL
+    m_kind = t.a0[lanes, p_row]
+    aidx = t.a1[lanes, p_row]
+    bank = t.addr_bank[aidx]
+    key = (lanes - s["rr"][bank]) % n
+    big = n + 1
+    kmat = xp.where(
+        req[None, :] & (bank[None, :] == xp.arange(n_banks)[:, None]),
+        key[None, :], big,
+    )
+    wlane = xp.argmin(kmat, axis=1)
+    has = kmat[xp.arange(n_banks), wlane] < big
+    win = xp.zeros(n, dtype=bool)
+    if xp is np:
+        win = win.copy()
+        win[wlane[has]] = True
+    else:
+        # scatter-add, not set: banks with no requester still argmin to
+        # lane 0 with has=False, and a duplicate-index set could let
+        # that clobber lane 0's real grant
+        win = xp.zeros(n, dtype=xp.int32).at[wlane].add(
+            has.astype(xp.int32)
+        ) > 0
+    conflicts = s["conflicts"] + req.sum() - has.sum()
+    rr = _set(t, s["rr"], xp.arange(n_banks), (wlane + 1) % n, has)
+    # effects
+    cnt = s["cnt"]
+    cnt = _add(t, cnt, 6 * xp.ones(n, dtype=xp.int32), 1, win)  # tcdm
+    val = s["tcdm"][aidx]
+    is_poll = win & (r_kind == T_POLL)
+    is_tas = win & (m_kind == _MK_TAS)
+    cnt = _add(t, cnt, 7 * xp.ones(n, dtype=xp.int32), 1, is_tas)  # tas
+    # tas (Mem or Poll) writes -1 and pays the 3-cycle latency
+    tcdm = _set(t, s["tcdm"], aidx, -1, is_tas)
+    base = xp.where(is_tas, tas_cycles - 1, 0)
+    # Poll: hit vs miss
+    until = t.a2[lanes, p_row]
+    hit_c, miss_c = t.a3[lanes, p_row], t.a4[lanes, p_row]
+    hit_i, miss_i = t.a5[lanes, p_row], t.a6[lanes, p_row]
+    hit = is_poll & (val == until)
+    miss = is_poll & (val != until)
+    busy = s["busy"]
+    busy = xp.where(hit, base + hit_c, busy)
+    busy = xp.where(miss, base + miss_c, busy)
+    cnt = _add(t, cnt, 5 * xp.ones(n, dtype=xp.int32),
+               xp.where(hit, hit_i, miss_i), is_poll)
+    R = xp.where(hit, val, s["R"])
+    # plain Mem
+    is_lw = win & (r_kind == T_MEM) & (m_kind == _MK_LW)
+    is_sw = win & (r_kind == T_MEM) & (m_kind == _MK_SW)
+    is_mtas = win & (r_kind == T_MEM) & (m_kind == _MK_TAS)
+    R = xp.where(is_lw | is_mtas, val, R)
+    R = xp.where(is_sw, 0, R)
+    tcdm = _set(t, tcdm, aidx, s["pdata"], is_sw)
+    busy = xp.where(is_mtas, tas_cycles - 1, busy)
+    busy = xp.where(is_lw | is_sw, busy, busy)
+    # resolution: winners go ACTIVE; polls stay armed on a miss
+    done_req = win & ~miss
+    pend = xp.where(done_req, -1, pend)
+    new_st = xp.where(win, _X_ACTIVE, st)
+    s = dict(s)
+    s.update(st=new_st, pend=pend, busy=busy, R=R, tcdm=tcdm, rr=rr,
+             cnt=cnt, conflicts=conflicts)
+    return s
+
+
+@_scoped("scu.account")
+def _account(t, s):
+    xp, n = t.xp, t.n
+    st = s["st"]
+    clocked = st != _X_DONE
+    act = st == _X_ACTIVE
+    stall = st == _X_STALL
+    cnt = s["cnt"]
+    inc = xp.stack([
+        clocked.astype(xp.int32),  # active
+        act.astype(xp.int32),  # comp
+        stall.astype(xp.int32),  # wait
+        xp.zeros(n, dtype=xp.int32),  # gated
+        stall.astype(xp.int32),  # stall
+    ])
+    if xp is np:
+        cnt = cnt.copy()
+        cnt[:5] += inc
+    else:
+        cnt = cnt.at[:5].add(inc)
+    s = dict(s)
+    s["cnt"] = cnt
+    s["cycle"] = s["cycle"] + 1
+    return s
+
+
+def _cycle_step(t, s):
+    xp, n = t.xp, t.n
+    # Phase 1: issue.  busy countdown; armed polls re-enter the queue
+    # (one instruction, like the engine's re-issue); everyone else
+    # fetches through the table until a data op lands.
+    st, busy, pend = s["st"], s["busy"], s["pend"]
+    act = st == _X_ACTIVE
+    counting = act & (busy > 0)
+    advancing = act & (busy <= 0)
+    s = dict(s)
+    s["busy"] = xp.where(counting, busy - 1, busy)
+    reissue = advancing & (pend >= 0)
+    s["st"] = xp.where(reissue, _X_STALL, st)
+    s["cnt"] = _add(t, s["cnt"], 5 * xp.ones(n, dtype=xp.int32), 1, reissue)
+    s["fetch"] = advancing & (pend < 0)
+    # decode until every fetching lane reached a data op or halted
+    if xp is np:
+        while bool(np.any(s["fetch"])):
+            s = _issue_data(t, s)
+            if not bool(np.any(s["fetch"])):
+                break
+            s = _decode_step(t, s)
+    else:
+        import jax
+
+        def body(ss):
+            ss = _issue_data(t, ss)
+            return _decode_step(t, ss)
+
+        s = jax.lax.while_loop(
+            lambda ss: ss["fetch"].any(), body, s,
+        )
+        s = _issue_data(t, s)
+    s.pop("fetch", None)
+    # Phase 2: arbitration + grants.  Phase 5: accounting.
+    s = _grant(t, s)
+    s = _account(t, s)
+    return s
+
+
+def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int):
+    """Run the packed int32 table ``(lanes, rows, 9)`` from a cleared
+    cluster until every lane halts or ``max_cycles`` cycles pass; the final
+    state.  ``addr_bank`` is each TCDM address's bank.  Under jax this is
+    the body of :func:`_jitted_execute`, so the tables are its inputs."""
+    n, length, _ = tab.shape
+    t = _Tables(
+        xp, tab[:, :, 0], tab[:, :, 1], tab[:, :, 2], tab[:, :, 3], tab[:, :, 4],
+        tab[:, :, 5], tab[:, :, 6], tab[:, :, 7], tab[:, :, 8], addr_bank,
+        xp.arange(n), n, n_banks, tas_cycles,
+    )
+    state = {
+        "pc": xp.zeros(n, dtype=xp.int32),
+        "rep": xp.zeros(n, dtype=xp.int32),
+        "R": xp.zeros(n, dtype=xp.int32),
+        "st": xp.zeros(n, dtype=xp.int32),
+        "busy": xp.zeros(n, dtype=xp.int32),
+        "pend": xp.full((n,), -1, dtype=xp.int32),  # row idx of pending op
+        "pdata": xp.zeros(n, dtype=xp.int32),  # latched store data
+        "tcdm": xp.zeros(addr_bank.shape[0], dtype=xp.int32),
+        "rr": xp.zeros(n_banks, dtype=xp.int32),
+        "cnt": xp.zeros((len(_COUNTERS), n), dtype=xp.int32),
+        "conflicts": xp.zeros((), dtype=xp.int32),
+        "fin": xp.full((n,), -1, dtype=xp.int32),
+        "cycle": xp.zeros((), dtype=xp.int32),
+        "ctr": xp.full((n, length), -1, dtype=xp.int32),
+    }
+    if xp is np:
+        while not np.all(state["st"] == _X_DONE) and state["cycle"] < max_cycles:
+            state["fetch"] = np.zeros(n, dtype=bool)
+            state = _cycle_step(t, state)
+        return state
+    import jax
+
+    def cond(s):
+        return (~(s["st"] == _X_DONE).all()) & (s["cycle"] < max_cycles)
+
+    def body(s):
+        obs.count("scu.loop_traces")  # runs only while jax traces the body
+        s = dict(s)
+        s["fetch"] = xp.zeros(n, dtype=bool)
+        return _cycle_step(t, s)
+
+    return jax.lax.while_loop(cond, body, state)
+
+
+@functools.cache
+def _jitted_execute():
+    """:func:`_execute` on ``jax.numpy`` as one ``jax.jit`` program, built
+    on first use (jax is optional).  jax keeps one executable per table
+    shape, ``n_banks`` and ``tas_cycles``; ``max_cycles`` is an input."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(functools.partial(_execute, jnp),
+                   static_argnames=("n_banks", "tas_cycles"))
 
 
 def run_traces_xp(
@@ -864,22 +1187,27 @@ def run_traces_xp(
     per-bank round-robin arbitration, Poll retry shadows, phase-5
     accounting) where every phase is an array kernel over all lanes -- no
     per-micro-op Python in the loop.  ``xp`` selects the array namespace:
-    ``numpy`` (default; the no-jax CI path) or ``jax.numpy`` inside
-    :func:`run_traces_jax`.  Returns a dict with ``cycles``, the nine
-    counter rows, ``bank_conflicts``, ``finished_at`` and the final tcdm
-    contents; parity vs the generator engine is enforced by
-    ``tests/test_trace.py``.
+    ``numpy`` (default; the no-jax CI path) runs the cycle loop in Python;
+    ``jax.numpy`` (what :func:`run_traces_jax` passes) runs it as
+    :func:`_jitted_execute`, one compiled program per table shape that jax
+    keeps in memory, so a call with a shape seen before neither traces nor
+    compiles.  Both run the same phase functions.  Returns a dict with
+    ``cycles``, the nine counter rows, ``bank_conflicts``, ``finished_at``
+    and the final tcdm contents; parity vs the generator engine is enforced
+    by ``tests/test_trace.py``.
 
     Consumes the programs (single-use), mirroring the cursor path.
 
     Each call records host spans (:mod:`repro.obs`): ``scu.run`` around it
-    all, and in turn ``scu.pack`` (host tables), ``scu.stage`` (tables and
-    initial state in ``xp``), ``scu.loop`` (the loop; under jax its trace,
-    lowering, compile or cache read and enqueue), ``scu.wait`` (the host
-    waiting on the device) and ``scu.readback``.  The jax loop body counts
-    ``scu.loop_traces`` each time it is traced, and its issue, decode, grant
-    and account phases carry the named scopes ``scu.issue``, ``scu.decode``,
-    ``scu.grant`` and ``scu.account``.
+    all, and in turn ``scu.pack`` (host tables), ``scu.stage`` (the int32
+    table and the address banks; under jax their upload to the device),
+    ``scu.loop`` (the loop from a cleared state; under jax the compiled
+    call: on a new shape its trace, lowering and compile or cache read,
+    then the enqueue), ``scu.wait`` (the host waiting on the device) and
+    ``scu.readback``.  The jax loop body counts ``scu.loop_traces`` each
+    time it is traced, so once per new shape, and its issue, decode, grant
+    and account phases carry the named scopes ``scu.issue``,
+    ``scu.decode``, ``scu.grant`` and ``scu.account``.
     """
     with obs.span("scu.run"):
         with obs.span("scu.pack"):
@@ -888,7 +1216,6 @@ def run_traces_xp(
                     raise RuntimeError("TraceProgram already consumed (single-use)")
                 p._consumed = True
             tab_np, addrs_np = _pack_tables(programs)
-            n, length, _ = tab_np.shape
             is_np = xp is np
             # All state is int32 under both namespaces (jax without x64 has no
             # int64).  A counter grows by at most 2 + the largest poll instruction
@@ -900,274 +1227,16 @@ def run_traces_xp(
                     "executor's int32 state"
                 )
         with obs.span("scu.stage"):
-            tab = xp.asarray(tab_np.astype(np.int32))
-            op_k = tab[:, :, 0]
-            rep_n = tab[:, :, 1]
-            a0, a1, a2 = tab[:, :, 2], tab[:, :, 3], tab[:, :, 4]
-            a3, a4, a5, a6 = tab[:, :, 5], tab[:, :, 6], tab[:, :, 7], tab[:, :, 8]
-            addr_bank = xp.asarray(((addrs_np >> 2) % n_banks).astype(np.int32))
-            lanes = xp.arange(n)
-
-            state = {
-                "pc": xp.zeros(n, dtype=xp.int32),
-                "rep": xp.zeros(n, dtype=xp.int32),
-                "R": xp.zeros(n, dtype=xp.int32),
-                "st": xp.zeros(n, dtype=xp.int32),
-                "busy": xp.zeros(n, dtype=xp.int32),
-                "pend": xp.full((n,), -1, dtype=xp.int32),  # row idx of pending op
-                "pdata": xp.zeros(n, dtype=xp.int32),  # latched store data
-                "tcdm": xp.zeros(len(addrs_np), dtype=xp.int32),
-                "rr": xp.zeros(n_banks, dtype=xp.int32),
-                "cnt": xp.zeros((len(_COUNTERS), n), dtype=xp.int32),
-                "conflicts": xp.zeros((), dtype=xp.int32),
-                "fin": xp.full((n,), -1, dtype=xp.int32),
-                "cycle": xp.zeros((), dtype=xp.int32),
-            }
-            state["ctr"] = xp.full((n, length), -1, dtype=xp.int32)
-        with obs.span("scu.loop"):
-            def _set(arr, idx, val, mask):
-                # masked scatter: only the masked lanes write.  Unmasked lanes may
-                # share an index with a writer (an idle lane's pending row defaults
-                # to row 0), so they must not write back the old value -- with
-                # duplicate indices that could clobber the real write.
-                if is_np:
-                    out = arr.copy()
-                    out[idx[mask]] = np.broadcast_to(val, idx.shape)[mask]
-                    return out
-                return arr.at[xp.where(mask, idx, arr.shape[0])].set(val, mode="drop")
-
-            def _add(arr, idx, val, mask):
-                # per-lane counter bump: arr[idx[lane], lane] += val[lane] where mask
-                if is_np:
-                    out = arr.copy()
-                    v = val if np.isscalar(val) else val[mask]
-                    np.add.at(out, (idx[mask], np.asarray(lanes)[mask]), v)
-                    return out
-                return arr.at[idx, lanes].add(xp.where(mask, val, 0))
-
-            @_scoped("scu.decode", not is_np)
-            def decode_step(s):
-                """Resolve one control row for every lane that needs a fetch."""
-                pc, rep, R, st = s["pc"], s["rep"], s["R"], s["st"]
-                row_k = xp.take_along_axis(op_k, pc[:, None], axis=1)[:, 0]
-                fetching = s["fetch"] & (st == _X_ACTIVE)
-                is_ctrl = fetching & (row_k >= T_JMP)
-                r0 = xp.take_along_axis(a0, pc[:, None], axis=1)[:, 0]
-                r1 = xp.take_along_axis(a1, pc[:, None], axis=1)[:, 0]
-                # JMP
-                jmp = is_ctrl & (row_k == T_JMP)
-                new_pc = xp.where(jmp, r0, pc)
-                # BR: taken when R == imm
-                br = is_ctrl & (row_k == T_BR)
-                new_pc = xp.where(br, xp.where(R == r0, r1, pc + 1), new_pc)
-                # LOOP: per-(lane, row) counters; -1 = not armed yet
-                lp = is_ctrl & (row_k == T_LOOP)
-                ctr = s["ctr"]
-                cur = xp.take_along_axis(ctr, pc[:, None], axis=1)[:, 0]
-                cur = xp.where(cur < 0, r1, cur)
-                take = lp & (cur > 0)
-                new_pc = xp.where(lp, xp.where(cur > 0, r0, pc + 1), new_pc)
-                new_ctr_val = xp.where(take, cur - 1, -1)
-                if is_np:
-                    ctr = ctr.copy()
-                    ctr[lanes[lp], pc[lp]] = new_ctr_val[lp]
-                else:
-                    ctr = ctr.at[lanes, pc].set(
-                        xp.where(lp, new_ctr_val, ctr[lanes, pc])
-                    )
-                # HALT
-                halt = is_ctrl & (row_k == T_HALT)
-                st = xp.where(halt, _X_DONE, st)
-                fin = xp.where(halt & (s["fin"] < 0), s["cycle"], s["fin"])
-                s = dict(s)
-                s.update(pc=new_pc, st=st, fin=fin, ctr=ctr)
-                s["fetch"] = fetching & is_ctrl & ~halt
-                return s
-
-            @_scoped("scu.issue", not is_np)
-            def issue_data(s):
-                """Lanes whose pc sits on a data row: issue it (instr, busy/stall)."""
-                pc, rep = s["pc"], s["rep"]
-                fetch = s["fetch"]
-                row_k = xp.take_along_axis(op_k, pc[:, None], axis=1)[:, 0]
-                data = fetch & (row_k <= T_SCU)
-                rn = xp.take_along_axis(rep_n, pc[:, None], axis=1)[:, 0]
-                r = xp.where(rep > 0, rep, rn) - 1
-                new_pc = xp.where(data & (r == 0), pc + 1, pc)
-                new_rep = xp.where(data, r, rep)
-                cnt = s["cnt"]
-                cnt = _add(cnt, 5 * xp.ones(n, dtype=xp.int32), 1, data)  # instructions
-                # COMPUTE: busy = max(0, c - 1), stay ACTIVE
-                c0 = xp.take_along_axis(a0, pc[:, None], axis=1)[:, 0]
-                comp = data & (row_k == T_COMPUTE)
-                busy = xp.where(comp, xp.maximum(c0 - 1, 0), s["busy"])
-                # MEM / POLL: pend at the issuing row, STALL; delta stores latch now
-                memp = data & ((row_k == T_MEM) | (row_k == T_POLL))
-                st = xp.where(memp, _X_STALL, s["st"])
-                pend = xp.where(memp, pc, s["pend"])
-                d_imm = xp.take_along_axis(a2, pc[:, None], axis=1)[:, 0]
-                d_flag = xp.take_along_axis(a3, pc[:, None], axis=1)[:, 0]
-                pdata = xp.where(
-                    data & (row_k == T_MEM),
-                    xp.where(d_flag == 1, s["R"] + d_imm, d_imm),
-                    s["pdata"],
-                )
-                s = dict(s)
-                s.update(pc=new_pc, rep=new_rep, busy=busy, st=st, pend=pend,
-                         pdata=pdata, cnt=cnt)
-                s["fetch"] = s["fetch"] & ~data
-                return s
-
-            @_scoped("scu.grant", not is_np)
-            def grant(s):
-                """Per-bank round-robin arbitration + transaction effects."""
-                st, pend = s["st"], s["pend"]
-                req = st == _X_STALL
-                p_row = xp.where(req, pend, 0)
-                r_kind = op_k[lanes, p_row]  # T_MEM / T_POLL
-                m_kind = a0[lanes, p_row]
-                aidx = a1[lanes, p_row]
-                bank = addr_bank[aidx]
-                key = (lanes - s["rr"][bank]) % n
-                big = n + 1
-                kmat = xp.where(
-                    req[None, :] & (bank[None, :] == xp.arange(n_banks)[:, None]),
-                    key[None, :], big,
-                )
-                wlane = xp.argmin(kmat, axis=1)
-                has = kmat[xp.arange(n_banks), wlane] < big
-                win = xp.zeros(n, dtype=bool)
-                if is_np:
-                    win = win.copy()
-                    win[wlane[has]] = True
-                else:
-                    # scatter-add, not set: banks with no requester still argmin to
-                    # lane 0 with has=False, and a duplicate-index set could let
-                    # that clobber lane 0's real grant
-                    win = xp.zeros(n, dtype=xp.int32).at[wlane].add(
-                        has.astype(xp.int32)
-                    ) > 0
-                conflicts = s["conflicts"] + req.sum() - has.sum()
-                rr = _set(s["rr"], xp.arange(n_banks), (wlane + 1) % n, has)
-                # effects
-                cnt = s["cnt"]
-                cnt = _add(cnt, 6 * xp.ones(n, dtype=xp.int32), 1, win)  # tcdm
-                val = s["tcdm"][aidx]
-                is_poll = win & (r_kind == T_POLL)
-                is_tas = win & (m_kind == _MK_TAS)
-                cnt = _add(cnt, 7 * xp.ones(n, dtype=xp.int32), 1, is_tas)  # tas
-                # tas (Mem or Poll) writes -1 and pays the 3-cycle latency
-                tcdm = _set(s["tcdm"], aidx, -1, is_tas)
-                base = xp.where(is_tas, tas_cycles - 1, 0)
-                # Poll: hit vs miss
-                until = a2[lanes, p_row]
-                hit_c, miss_c = a3[lanes, p_row], a4[lanes, p_row]
-                hit_i, miss_i = a5[lanes, p_row], a6[lanes, p_row]
-                hit = is_poll & (val == until)
-                miss = is_poll & (val != until)
-                busy = s["busy"]
-                busy = xp.where(hit, base + hit_c, busy)
-                busy = xp.where(miss, base + miss_c, busy)
-                cnt = _add(cnt, 5 * xp.ones(n, dtype=xp.int32),
-                           xp.where(hit, hit_i, miss_i), is_poll)
-                R = xp.where(hit, val, s["R"])
-                # plain Mem
-                is_lw = win & (r_kind == T_MEM) & (m_kind == _MK_LW)
-                is_sw = win & (r_kind == T_MEM) & (m_kind == _MK_SW)
-                is_mtas = win & (r_kind == T_MEM) & (m_kind == _MK_TAS)
-                R = xp.where(is_lw | is_mtas, val, R)
-                R = xp.where(is_sw, 0, R)
-                tcdm = _set(tcdm, aidx, s["pdata"], is_sw)
-                busy = xp.where(is_mtas, tas_cycles - 1, busy)
-                busy = xp.where(is_lw | is_sw, busy, busy)
-                # resolution: winners go ACTIVE; polls stay armed on a miss
-                done_req = win & ~miss
-                pend = xp.where(done_req, -1, pend)
-                new_st = xp.where(win, _X_ACTIVE, st)
-                s = dict(s)
-                s.update(st=new_st, pend=pend, busy=busy, R=R, tcdm=tcdm, rr=rr,
-                         cnt=cnt, conflicts=conflicts)
-                return s
-
-            @_scoped("scu.account", not is_np)
-            def account(s):
-                st = s["st"]
-                clocked = st != _X_DONE
-                act = st == _X_ACTIVE
-                stall = st == _X_STALL
-                cnt = s["cnt"]
-                inc = xp.stack([
-                    clocked.astype(xp.int32),  # active
-                    act.astype(xp.int32),  # comp
-                    stall.astype(xp.int32),  # wait
-                    xp.zeros(n, dtype=xp.int32),  # gated
-                    stall.astype(xp.int32),  # stall
-                ])
-                if is_np:
-                    cnt = cnt.copy()
-                    cnt[:5] += inc
-                else:
-                    cnt = cnt.at[:5].add(inc)
-                s = dict(s)
-                s["cnt"] = cnt
-                s["cycle"] = s["cycle"] + 1
-                return s
-
-            def cycle_step(s):
-                # Phase 1: issue.  busy countdown; armed polls re-enter the queue
-                # (one instruction, like the engine's re-issue); everyone else
-                # fetches through the table until a data op lands.
-                st, busy, pend = s["st"], s["busy"], s["pend"]
-                act = st == _X_ACTIVE
-                counting = act & (busy > 0)
-                advancing = act & (busy <= 0)
-                s = dict(s)
-                s["busy"] = xp.where(counting, busy - 1, busy)
-                reissue = advancing & (pend >= 0)
-                s["st"] = xp.where(reissue, _X_STALL, st)
-                s["cnt"] = _add(s["cnt"], 5 * xp.ones(n, dtype=xp.int32), 1, reissue)
-                s["fetch"] = advancing & (pend < 0)
-                # decode until every fetching lane reached a data op or halted
-                if is_np:
-                    while bool(np.any(s["fetch"])):
-                        s = issue_data(s)
-                        if not bool(np.any(s["fetch"])):
-                            break
-                        s = decode_step(s)
-                else:
-                    import jax
-
-                    def body(ss):
-                        ss = issue_data(ss)
-                        return decode_step(ss)
-
-                    s = jax.lax.while_loop(
-                        lambda ss: ss["fetch"].any(), body, s,
-                    )
-                    s = issue_data(s)
-                s.pop("fetch", None)
-                # Phase 2: arbitration + grants.  Phase 5: accounting.
-                s = grant(s)
-                s = account(s)
-                return s
-
-            if is_np:
-                while not np.all(state["st"] == _X_DONE) and state["cycle"] < max_cycles:
-                    state["fetch"] = np.zeros(n, dtype=bool)
-                    state = cycle_step(state)
-            else:
+            tab = tab_np.astype(np.int32)
+            addr_bank = ((addrs_np >> 2) % n_banks).astype(np.int32)
+            if not is_np:
                 import jax
 
-                def cond(s):
-                    return (~(s["st"] == _X_DONE).all()) & (s["cycle"] < max_cycles)
-
-                def body(s):
-                    obs.count("scu.loop_traces")  # runs only while jax traces the body
-                    s = dict(s)
-                    s["fetch"] = xp.zeros(n, dtype=bool)
-                    return cycle_step(s)
-
-                state = jax.lax.while_loop(cond, body, state)
+                tab, addr_bank = jax.device_put((tab, addr_bank))
+        with obs.span("scu.loop"):
+            run = functools.partial(_execute, np) if is_np else _jitted_execute()
+            state = run(tab, addr_bank, np.int32(max_cycles), n_banks=n_banks,
+                        tas_cycles=tas_cycles)
         with obs.span("scu.wait"):
             if not is_np:
                 jax.block_until_ready(state)
@@ -1195,8 +1264,9 @@ def run_traces_jax(
     tas_cycles: int = 3,
     max_cycles: int = 10_000_000,
 ):
-    """The same batched executor as one ``jax.jit`` program (XLA while
-    loop).  Requires jax; gate callers on :data:`repro.compat.HAS_JAX`."""
+    """:func:`run_traces_xp` on ``jax.numpy``: the cycle loop runs as one
+    ``jax.jit`` program (an XLA while loop) kept in memory per table shape.
+    Requires jax; gate callers on :data:`repro.compat.HAS_JAX`."""
     from repro.compat import HAS_JAX
 
     if not HAS_JAX:
