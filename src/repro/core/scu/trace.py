@@ -23,8 +23,10 @@ Three consumers:
   below the vectorization threshold and spin through shared-state phases
   the quiescent/spin tiers cannot jump.
 * :func:`run_traces_xp` -- a self-contained batched array executor for
-  pure-TCDM traces: program counters, round-robin arbitration and phase-5
-  accounting as array kernels (numpy, or one ``jax.jit`` program behind
+  traces of TCDM and SCU ops (all but the event FIFO): program counters,
+  round-robin arbitration, the SCU's event units, ``elw`` sleep and wake,
+  its barrier, mutex and notifier extensions, and phase-5 accounting as
+  array kernels (numpy, or one ``jax.jit`` program behind
   :mod:`repro.compat`) with no per-micro-op Python in the loop.
 
 Value semantics: a trace tracks one register ``R`` mirroring the engine's
@@ -54,7 +56,8 @@ import numpy as np
 
 from repro import obs
 
-from .engine import _COUNTERS, Compute, Mem, Poll, Scu
+from .engine import _COUNTERS, Cluster, Compute, Mem, Poll, Scu
+from .scu_unit import EV
 
 __all__ = [
     "T_COMPUTE",
@@ -85,7 +88,7 @@ __all__ = [
 T_COMPUTE = 0  # a0 = cycles
 T_MEM = 1  # a0 = kind code, a1 = addr, a2 = data, a3 = 1 if data is R + a2
 T_POLL = 2  # a0 = kind, a1 = addr, a2 = until, a3..a6 = hit_c/miss_c/hit_i/miss_i
-T_SCU = 3  # a0 = index into the program's scu op pool
+T_SCU = 3  # a0 = index into the program's scu op pool (packed: see _SCU_OPS)
 T_JMP = 4  # a0 = target row
 T_BR = 5  # a0 = immediate, a1 = target row; taken when R == a0
 T_LOOP = 6  # a0 = target row, a1 = count of back-jumps before falling through
@@ -803,42 +806,95 @@ class TraceRunMonitor:
 
 
 # --------------------------------------------------------------------------
-# Batched array executor for pure-TCDM traces (numpy, and jax.jit via compat)
+# Batched array executor for traces (numpy, and jax.jit via compat)
 # --------------------------------------------------------------------------
 
-_X_ACTIVE, _X_STALL, _X_DONE = 0, 1, 2
+# lane states: the engine's CoreState, with STALL_MEM as _X_STALL
+_X_ACTIVE, _X_STALL, _X_DONE, _X_SCU, _X_SLEEP, _X_WAKE = 0, 1, 2, 3, 4, 5
 _I32_MAX = int(np.iinfo(np.int32).max)
+
+# SCU ops the executor runs: a packed T_SCU row is (T_SCU, 1, op code,
+# instance, data).  The key is (kind, address tag, address role); a read's
+# role is any.  The event FIFO and every other op stay on the engine.
+(_S_BAR_WAIT, _S_MTX_LOCK, _S_NTF_WAIT, _S_EV_WAIT, _S_MTX_UNLOCK, _S_NTF_TRIG,
+ _S_MASK, _S_CLEAR, _S_RD_BUF, _S_RD_BAR, _S_RD_MTX) = range(11)
+_SCU_OPS = {
+    ("elw", "barrier", "wait_all"): _S_BAR_WAIT,
+    ("elw", "mutex", "lock"): _S_MTX_LOCK,
+    ("elw", "notifier", "wait"): _S_NTF_WAIT,
+    ("elw", "event", "wait_any"): _S_EV_WAIT,
+    ("write", "mutex", "unlock"): _S_MTX_UNLOCK,
+    ("write", "notifier", "trigger"): _S_NTF_TRIG,
+    ("write", "mask", "event"): _S_MASK,
+    ("write", "buffer", "clear"): _S_CLEAR,
+    ("read", "buffer", None): _S_RD_BUF,
+    ("read", "barrier", None): _S_RD_BAR,
+    ("read", "mutex", None): _S_RD_MTX,
+}
+_INDEXED = ("barrier", "mutex", "notifier")  # tags whose addr[1] is an instance
+_EV_BARRIER_BIT, _EV_MUTEX_BIT = np.int32(1 << EV.BARRIER), np.int32(1 << EV.MUTEX)
+
+
+def _encode_scu(op: Scu, n: int) -> Tuple[int, int]:
+    """``op``'s (code, instance), or a ValueError that names the op."""
+    addr = op.addr
+    tag = addr[0] if isinstance(addr, tuple) and addr else None
+    inst = addr[1] if tag in _INDEXED and len(addr) > 1 else 0
+    role = None if op.kind == "read" else (addr[-1] if tag is not None else None)
+    code = _SCU_OPS.get((op.kind, tag, role))
+    why = None
+    if tag == "fifo":
+        why = "the event FIFO runs on the engine only"
+    elif code is None or not isinstance(inst, int):
+        why = "the array executor does not encode it"
+    elif inst < 0 or (tag == "notifier" and inst >= 8):
+        why = "no such extension instance"
+    elif code == _S_RD_BAR and n > 31:
+        why = "a barrier status word holds at most 31 cores in int32"
+    if why is not None:
+        raise ValueError(f"array executor cannot run SCU op {op!r}: {why}")
+    return code, inst
 
 
 def _pack_tables(programs: Sequence[TraceProgram]):
-    """Flatten trace tables into padded per-lane numpy arrays."""
+    """Flatten trace tables into padded per-lane numpy arrays.  Also returns
+    the SCU's shape, ``(barriers, mutexes)`` the tables address, or ``None``
+    when no table has an SCU row."""
     for p in programs:
         if not p.is_traced:
             raise ValueError("array executor needs pure traced programs")
-        for row in p.rows:
-            if row[0] == T_SCU:
-                raise ValueError(
-                    "array executor supports pure-TCDM traces only "
-                    "(SCU rows need the full engine)"
-                )
     n = len(programs)
     length = max(len(p.rows) for p in programs)
     addrs = sorted(set().union(*(p.addresses() for p in programs)))
     addr_idx = {a: i for i, a in enumerate(addrs)}
     tab = np.zeros((n, length, 9), dtype=np.int64)
     tab[:, :, 0] = T_HALT
+    n_bar = n_mtx = 0
+    has_scu = False
     for lane, p in enumerate(programs):
         for r, row in enumerate(p.rows):
             tab[lane, r] = row
             if row[0] in (T_MEM, T_POLL):
                 tab[lane, r, 3] = addr_idx[row[3]]
-    return tab, np.array(addrs, dtype=np.int64)
+            elif row[0] == T_SCU:
+                op = p.scu_pool[row[2]]
+                code, inst = _encode_scu(op, n)
+                tab[lane, r, 2:] = (code, inst, op.data, 0, 0, 0, 0)
+                has_scu = True
+                if code in (_S_BAR_WAIT, _S_RD_BAR):
+                    n_bar = max(n_bar, inst + 1)
+                elif code in (_S_MTX_LOCK, _S_MTX_UNLOCK, _S_RD_MTX):
+                    n_mtx = max(n_mtx, inst + 1)
+    scu = (max(n_bar, 1), max(n_mtx, 1)) if has_scu else None
+    return tab, np.array(addrs, dtype=np.int64), scu
 
 
 class _Tables(NamedTuple):
     """The executor's read-only inputs, passed to every phase: the packed
     table's columns (lane x row), each TCDM address's bank, the lane index
-    and the static sizes.  ``xp`` is the array namespace they live in."""
+    and the static sizes.  ``xp`` is the array namespace they live in.
+    ``scu`` is ``(barriers, mutexes)``, or ``None`` for tables without SCU
+    rows, which then carry no SCU state and run no SCU phase."""
 
     xp: Any
     op_k: Any
@@ -855,6 +911,7 @@ class _Tables(NamedTuple):
     n: int
     n_banks: int
     tas_cycles: int
+    scu: Optional[Tuple[int, int]]
 
 
 def _scoped(name: str):
@@ -962,6 +1019,11 @@ def _issue_data(t, s):
     memp = data & ((row_k == T_MEM) | (row_k == T_POLL))
     st = xp.where(memp, _X_STALL, s["st"])
     pend = xp.where(memp, pc, s["pend"])
+    if t.scu:
+        # SCU: pend at the issuing row, STALL_SCU until the link services it
+        scu = data & (row_k == T_SCU)
+        st = xp.where(scu, _X_SCU, st)
+        pend = xp.where(scu, pc, pend)
     d_imm = xp.take_along_axis(t.a2, pc[:, None], axis=1)[:, 0]
     d_flag = xp.take_along_axis(t.a3, pc[:, None], axis=1)[:, 0]
     pdata = xp.where(
@@ -1056,12 +1118,18 @@ def _account(t, s):
     clocked = st != _X_DONE
     act = st == _X_ACTIVE
     stall = st == _X_STALL
+    wait = stall
+    if t.scu:
+        # a sleeping lane is clock gated; STALL_SCU and WAKE wait clocked
+        gated = st == _X_SLEEP
+        clocked = clocked & ~gated
+        wait = clocked & ~act
     cnt = s["cnt"]
     inc = xp.stack([
         clocked.astype(xp.int32),  # active
         act.astype(xp.int32),  # comp
-        stall.astype(xp.int32),  # wait
-        xp.zeros(n, dtype=xp.int32),  # gated
+        wait.astype(xp.int32),  # wait
+        gated.astype(xp.int32) if t.scu else xp.zeros(n, dtype=xp.int32),  # gated
         stall.astype(xp.int32),  # stall
     ])
     if xp is np:
@@ -1075,8 +1143,167 @@ def _account(t, s):
     return s
 
 
+def _or_over(t, x, axis: int):
+    """Bitwise OR of ``x`` along ``axis``."""
+    if t.xp is np:
+        return np.bitwise_or.reduce(x, axis=axis)
+    import jax
+
+    return jax.lax.reduce(x, np.int32(0), jax.lax.bitwise_or, (axis,))
+
+
+def _onehot(t, idx, size: int):
+    """``(size, lanes)`` bool: row ``i`` marks the lanes whose ``idx`` is ``i``."""
+    return idx[None, :] == t.xp.arange(size)[:, None]
+
+
+@_scoped("scu.sync")
+def _scu_evaluate(t, s):
+    """Phase 0: the extension comparators (the engine's ``SCU.evaluate``),
+    so events of the previous cycle's triggers are buffered now.  A barrier
+    every lane arrived at sends the barrier event to every lane and clears;
+    a free mutex with waiters elects the earliest arrival (lowest stamp,
+    then lowest lane, as the engine's queue orders them) and sends it the
+    mutex event."""
+    xp, lanes = t.xp, t.lanes
+    bar, stamp, owner = s["bar"], s["stamp"], s["owner"]
+    fire = bar.all(axis=1)
+    waiting = stamp >= 0
+    elect = (owner < 0) & waiting.any(axis=1)
+    el = xp.argmin(xp.where(waiting, stamp, _I32_MAX), axis=1).astype(xp.int32)
+    chosen = elect[:, None] & (lanes[None, :] == el[:, None])
+    s = dict(s)
+    s["bar"] = bar & ~fire[:, None]
+    s["owner"] = xp.where(elect, el, owner)
+    s["stamp"] = xp.where(chosen, -1, stamp)
+    s["ev_buf"] = (s["ev_buf"] | xp.where(fire.any(), _EV_BARRIER_BIT, 0)
+                   | xp.where(chosen.any(axis=0), _EV_MUTEX_BIT, 0))
+    return s
+
+
+@_scoped("scu.sync")
+def _scu_countdown(t, s):
+    """Phase 1 of a lane on an ``elw`` (the engine's ``Cluster._issue``): an
+    issued one counts down its sleep entry, then its clock is gated (Fig. 4
+    left); a granted one counts down its wake, then turns ACTIVE and
+    fetches with the rest (its ``busy`` is 0 since it issued the op)."""
+    xp = t.xp
+    st = s["st"]
+    entering = (st == _X_SCU) & s["elw"]
+    waking = st == _X_WAKE
+    entry = xp.where(entering, s["sleep_entry"] - 1, s["sleep_entry"])
+    wake = xp.where(waking, s["wake"] - 1, s["wake"])
+    st = xp.where(entering & (entry <= 0), _X_SLEEP, st)
+    st = xp.where(waking & (wake <= 0), _X_ACTIVE, st)
+    s = dict(s)
+    s.update(st=st, sleep_entry=entry, wake=wake)
+    return s
+
+
+@_scoped("scu.sync")
+def _scu_service(t, s):
+    """Phase 3: every lane's fresh transaction on its private SCU link,
+    with the effects the engine's ``Cluster._service_one`` gives them when
+    it takes the lanes in order.  A write or read completes (the lane
+    resumes next cycle with the read's value, 0 after a write); an ``elw``
+    triggers its extension once, arms its sleep entry and waits."""
+    xp, n, lanes = t.xp, t.n, t.lanes
+    n_bar, n_mtx = t.scu
+    st, pend, buf = s["st"], s["pend"], s["ev_buf"]
+    fresh = (st == _X_SCU) & ~s["elw"]
+    row = xp.where(fresh, pend, 0)
+    code = xp.where(fresh, t.a0[lanes, row], -1)
+    inst, data = t.a1[lanes, row], t.a2[lanes, row]
+    earlier = lanes[:, None] < lanes[None, :]  # [sender, lane]: sender first
+    # notifier triggers: event ``inst`` to the lanes set in ``data`` (0:
+    # all).  A lane clearing or reading its own buffer sees the triggers of
+    # the lanes before it and not those after it.
+    trig = code == _S_NTF_TRIG
+    reach = (data[:, None] == 0) | (((data[:, None] >> xp.minimum(lanes, 31)[None, :]) & 1) == 1)
+    sent = xp.where(trig[:, None] & reach, (1 << xp.where(trig, inst, 0))[:, None], 0)
+    before = _or_over(t, xp.where(earlier, sent, 0), 0)
+    after = _or_over(t, xp.where(earlier, 0, sent), 0)
+    seen = buf | before
+    buf = xp.where(code == _S_CLEAR, (seen & ~data) | after, seen | after)
+    # barrier arrivals; a status read sees the arrivals of earlier lanes
+    arrive = _onehot(t, inst, n_bar) & (code == _S_BAR_WAIT)[None, :]
+    b = xp.where(code == _S_RD_BAR, inst, 0)
+    status = s["bar"][b] | (arrive[b] & earlier.T)  # [lane, core]
+    # (barrier reads are refused above 31 lanes, so no shift passes 30)
+    bar_word = xp.where(status, 1 << xp.minimum(lanes, 30)[None, :], 0).sum(axis=1, dtype=xp.int32)
+    # mutexes: a lock joins the queue stamped with this cycle unless the
+    # lane waits or owns already; the owner's unlock frees it with its
+    # message; a read sees the mutex held unless its owner, an earlier
+    # lane, unlocked it
+    owner, stamp = s["owner"], s["stamp"]
+    on_mtx = _onehot(t, inst, n_mtx)
+    join = on_mtx & (code == _S_MTX_LOCK)[None, :] & (stamp < 0) & (owner[:, None] != lanes[None, :])
+    rel = on_mtx & (code == _S_MTX_UNLOCK)[None, :] & (owner[:, None] == lanes[None, :])
+    freed = rel.any(axis=1)
+    m = xp.where(code == _S_RD_MTX, inst, 0)
+    held = (owner[m] >= 0) & ~(freed[m] & (owner[m] < lanes))
+    value = xp.where(code == _S_RD_BUF, seen, 0)
+    value = xp.where(code == _S_RD_BAR, bar_word, value)
+    value = xp.where(code == _S_RD_MTX, held.astype(xp.int32), value)
+    is_elw = fresh & (code <= _S_EV_WAIT)
+    done = fresh & ~is_elw
+    s = dict(s)
+    s.update(
+        ev_buf=buf,
+        ev_mask=xp.where(code == _S_MASK, data, s["ev_mask"]),
+        bar=s["bar"] | arrive,
+        stamp=xp.where(join, s["cycle"], stamp),
+        owner=xp.where(freed, -1, owner),
+        msg=xp.where(freed, xp.where(rel, data[None, :], 0).sum(axis=1, dtype=xp.int32),
+                     s["msg"]),
+        R=xp.where(done, value, s["R"]),
+        st=xp.where(done, _X_ACTIVE, st),
+        pend=xp.where(done, -1, pend),
+        elw=s["elw"] | is_elw,
+        sleep_entry=xp.where(is_elw, Cluster.SLEEP_ENTRY_CYCLES, s["sleep_entry"]),
+        cnt=_add(t, s["cnt"], 8 * xp.ones(n, dtype=xp.int32), 1, fresh),  # scu
+    )
+    return s
+
+
+@_scoped("scu.sync")
+def _scu_wake(t, s):
+    """Phase 4: every issued ``elw`` polled against its lane's event buffer
+    (the engine's ``Cluster._wake_one``).  A hit clears the lines waited on
+    and answers with the mutex's message for a mutex, else with the
+    buffer; the lane wakes in ``WAKE_CYCLES``, one fewer if it never
+    slept."""
+    xp, lanes = t.xp, t.lanes
+    st, buf, elw = s["st"], s["ev_buf"], s["elw"]
+    row = xp.where(elw, s["pend"], 0)
+    code, inst = xp.where(elw, t.a0[lanes, row], -1), t.a1[lanes, row]
+    mask = s["ev_mask"]
+    wait = xp.where(code == _S_EV_WAIT, xp.where(mask != 0, mask, -1),
+                    1 << (EV.NOTIFIER0 + xp.where(code == _S_NTF_WAIT, inst, 0)))
+    wait = xp.where(code == _S_BAR_WAIT, _EV_BARRIER_BIT, wait)
+    wait = xp.where(code == _S_MTX_LOCK, _EV_MUTEX_BIT, wait)
+    hit = elw & ((buf & wait) != 0)
+    is_mtx = code == _S_MTX_LOCK
+    value = xp.where(is_mtx, s["msg"][xp.where(is_mtx, inst, 0)], buf)
+    woken = Cluster.WAKE_CYCLES - (st == _X_SCU).astype(xp.int32)
+    s = dict(s)
+    s.update(
+        ev_buf=xp.where(hit, buf & ~wait, buf),
+        R=xp.where(hit, value, s["R"]),
+        pend=xp.where(hit, -1, s["pend"]),
+        wake=xp.where(hit, woken, s["wake"]),
+        st=xp.where(hit, _X_WAKE, st),
+        elw=elw & ~hit,
+    )
+    return s
+
+
 def _cycle_step(t, s):
     xp, n = t.xp, t.n
+    if t.scu:
+        # Phase 0: comparators; then phase 1 of the lanes on an elw
+        s = _scu_evaluate(t, s)
+        s = _scu_countdown(t, s)
     # Phase 1: issue.  busy countdown; armed polls re-enter the queue
     # (one instruction, like the engine's re-issue); everyone else
     # fetches through the table until a data op lands.
@@ -1109,22 +1336,28 @@ def _cycle_step(t, s):
         )
         s = _issue_data(t, s)
     s.pop("fetch", None)
-    # Phase 2: arbitration + grants.  Phase 5: accounting.
+    # Phase 2: arbitration + grants.  Phases 3 and 4: the SCU's links and
+    # elw grants.  Phase 5: accounting.
     s = _grant(t, s)
+    if t.scu:
+        s = _scu_service(t, s)
+        s = _scu_wake(t, s)
     s = _account(t, s)
     return s
 
 
-def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int):
+def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
+             scu: Optional[Tuple[int, int]]):
     """Run the packed int32 table ``(lanes, rows, 9)`` from a cleared
     cluster until every lane halts or ``max_cycles`` cycles pass; the final
-    state.  ``addr_bank`` is each TCDM address's bank.  Under jax this is
-    the body of :func:`_jitted_execute`, so the tables are its inputs."""
+    state.  ``addr_bank`` is each TCDM address's bank; ``scu`` is
+    :func:`_pack_tables`'s SCU shape.  Under jax this is the body of
+    :func:`_jitted_execute`, so the tables are its inputs."""
     n, length, _ = tab.shape
     t = _Tables(
         xp, tab[:, :, 0], tab[:, :, 1], tab[:, :, 2], tab[:, :, 3], tab[:, :, 4],
         tab[:, :, 5], tab[:, :, 6], tab[:, :, 7], tab[:, :, 8], addr_bank,
-        xp.arange(n), n, n_banks, tas_cycles,
+        xp.arange(n), n, n_banks, tas_cycles, scu,
     )
     state = {
         "pc": xp.zeros(n, dtype=xp.int32),
@@ -1142,6 +1375,22 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int):
         "cycle": xp.zeros((), dtype=xp.int32),
         "ctr": xp.full((n, length), -1, dtype=xp.int32),
     }
+    if scu:
+        # the base units' registers, each lane's elw sequencing, and the
+        # extensions: barrier arrivals, mutex owner (-1: free), unlock
+        # message and each lane's queue stamp (-1: not waiting)
+        n_bar, n_mtx = scu
+        state.update(
+            ev_buf=xp.zeros(n, dtype=xp.int32),
+            ev_mask=xp.zeros(n, dtype=xp.int32),
+            elw=xp.zeros(n, dtype=bool),
+            sleep_entry=xp.zeros(n, dtype=xp.int32),
+            wake=xp.zeros(n, dtype=xp.int32),
+            bar=xp.zeros((n_bar, n), dtype=bool),
+            owner=xp.full((n_mtx,), -1, dtype=xp.int32),
+            msg=xp.zeros(n_mtx, dtype=xp.int32),
+            stamp=xp.full((n_mtx, n), -1, dtype=xp.int32),
+        )
     if xp is np:
         while not np.all(state["st"] == _X_DONE) and state["cycle"] < max_cycles:
             state["fetch"] = np.zeros(n, dtype=bool)
@@ -1165,12 +1414,13 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int):
 def _jitted_execute():
     """:func:`_execute` on ``jax.numpy`` as one ``jax.jit`` program, built
     on first use (jax is optional).  jax keeps one executable per table
-    shape, ``n_banks`` and ``tas_cycles``; ``max_cycles`` is an input."""
+    shape, ``n_banks``, ``tas_cycles`` and SCU shape; ``max_cycles`` is an
+    input."""
     import jax
     import jax.numpy as jnp
 
     return jax.jit(functools.partial(_execute, jnp),
-                   static_argnames=("n_banks", "tas_cycles"))
+                   static_argnames=("n_banks", "tas_cycles", "scu"))
 
 
 def run_traces_xp(
@@ -1181,12 +1431,14 @@ def run_traces_xp(
     max_cycles: int = 10_000_000,
     xp=np,
 ):
-    """Execute pure-TCDM traces as one batched array computation.
+    """Execute traces as one batched array computation.
 
-    A from-scratch implementation of the engine's TCDM semantics (issue,
-    per-bank round-robin arbitration, Poll retry shadows, phase-5
-    accounting) where every phase is an array kernel over all lanes -- no
-    per-micro-op Python in the loop.  ``xp`` selects the array namespace:
+    A from-scratch implementation of the engine's cycle semantics (issue,
+    per-bank round-robin arbitration, Poll retry shadows, the SCU's
+    comparators, private links and elw sleep and wake, phase-5 accounting)
+    where every phase is an array kernel over all lanes -- no per-micro-op
+    Python in the loop.  SCU rows are the ops of ``_SCU_OPS``; any other,
+    the event FIFO's first, raises a ValueError that names it.  ``xp`` selects the array namespace:
     ``numpy`` (default; the no-jax CI path) runs the cycle loop in Python;
     ``jax.numpy`` (what :func:`run_traces_jax` passes) runs it as
     :func:`_jitted_execute`, one compiled program per table shape that jax
@@ -1207,7 +1459,9 @@ def run_traces_xp(
     ``scu.readback``.  The jax loop body counts ``scu.loop_traces`` each
     time it is traced, so once per new shape, and its issue, decode, grant
     and account phases carry the named scopes ``scu.issue``,
-    ``scu.decode``, ``scu.grant`` and ``scu.account``.
+    ``scu.decode``, ``scu.grant`` and ``scu.account``, and the SCU phase of
+    a table with SCU rows ``scu.sync``.  The readback counts the job's SCU
+    transactions as ``scu.sync_ops``.
     """
     with obs.span("scu.run"):
         with obs.span("scu.pack"):
@@ -1215,7 +1469,7 @@ def run_traces_xp(
                 if p._consumed:
                     raise RuntimeError("TraceProgram already consumed (single-use)")
                 p._consumed = True
-            tab_np, addrs_np = _pack_tables(programs)
+            tab_np, addrs_np, scu = _pack_tables(programs)
             is_np = xp is np
             # All state is int32 under both namespaces (jax without x64 has no
             # int64).  A counter grows by at most 2 + the largest poll instruction
@@ -1229,6 +1483,8 @@ def run_traces_xp(
         with obs.span("scu.stage"):
             tab = tab_np.astype(np.int32)
             addr_bank = ((addrs_np >> 2) % n_banks).astype(np.int32)
+            if not len(addr_bank):  # no TCDM word: the grant phase still gathers one
+                addr_bank = np.zeros(1, dtype=np.int32)
             if not is_np:
                 import jax
 
@@ -1236,7 +1492,7 @@ def run_traces_xp(
         with obs.span("scu.loop"):
             run = functools.partial(_execute, np) if is_np else _jitted_execute()
             state = run(tab, addr_bank, np.int32(max_cycles), n_banks=n_banks,
-                        tas_cycles=tas_cycles)
+                        tas_cycles=tas_cycles, scu=scu)
         with obs.span("scu.wait"):
             if not is_np:
                 jax.block_until_ready(state)
@@ -1248,6 +1504,7 @@ def run_traces_xp(
                 name: np.asarray(state["cnt"][i])
                 for i, name in enumerate(_COUNTERS)
             }
+            obs.count("scu.sync_ops", int(counters["scu_accesses"].sum()))
             return {
                 "cycles": int(state["cycle"]),
                 "counters": counters,

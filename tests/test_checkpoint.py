@@ -239,9 +239,12 @@ def test_recycled_slot_residue_free(seed, policy, k):
 
     fl = SlotFleet(1, n)
     # dirty the slot: the previous tenant burns to a tight max_cycles cap,
-    # leaving lanes mid-SLEEP/STALL with latched events and pending ops
-    prev = _bench(rng.choice(POLICIES), n, iters=8,
-                  max_cycles=rng.randrange(60, 400))
+    # leaving lanes mid-SLEEP/STALL with latched events and pending ops.
+    # The cap lies below the tenant's own uncapped length, so it is cut.
+    prev_policy = rng.choice(POLICIES)
+    full = _reference(prev_policy, n, iters=8).cycles
+    prev = _bench(prev_policy, n, iters=8,
+                  max_cycles=rng.randrange(min(60, full // 2), min(400, full)))
     slot = fl.admit(prev)
     while True:
         fin = fl.advance()

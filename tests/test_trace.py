@@ -208,13 +208,196 @@ def test_run_traces_xp_is_single_use():
         run_traces_xp(progs, n_banks=4)
 
 
-def test_run_traces_xp_rejects_scu_rows():
+@pytest.mark.parametrize("kind,addr,why", [
+    ("elw", ("fifo", 2, "pop"), "event FIFO"),
+    ("write", ("fifo", 0, "push"), "event FIFO"),
+    ("write", 0x10, "does not encode"),
+    ("elw", ("notifier", 8, "wait"), "no such extension instance"),
+])
+def test_run_traces_xp_rejects_scu_rows(kind, addr, why):
+    """The SCU ops the executor does not run, the event FIFO's first, are
+    refused by name before anything runs."""
     tb = TraceBuilder()
     tb.compute(1)
-    tb.scu("write", 0x10, 1)
+    tb.scu(kind, addr, 1)
     tp = tb.build()
-    with pytest.raises(ValueError, match="SCU"):
+    with pytest.raises(ValueError, match=f"SCU op .*{addr[0] if isinstance(addr, tuple) else ''}.*{why}"):
         run_traces_xp([tp], n_banks=4)
+
+
+# Table 1's SCU columns and their baselines (the ``sim.table1-scu-8pe`` mix):
+# (primitive, policy, t_crit, sfr).  Every job sleeps and wakes through elw.
+_SCU_JOBS = [
+    ("barrier", "scu", 0, 0), ("barrier", "tas", 0, 0), ("barrier", "tree_ew", 0, 0),
+    ("mutex", "scu", 0, 0), ("mutex", "scu", 10, 0), ("mutex", "tas", 0, 0),
+    ("mutex", "tas", 10, 0), ("barrier", "scu", 0, 42),
+]
+
+
+def _scu_job(job, n, iters=3):
+    prim, policy, t_crit, sfr = job
+    if prim == "barrier":
+        return prep_barrier_bench(policy, n, sfr=sfr, iters=iters, compiled=True, mode="lockstep")
+    return prep_mutex_bench(policy, n, t_crit=t_crit, sfr=sfr, iters=iters, compiled=True,
+                            mode="lockstep")
+
+
+def _assert_matches_engine(res, cl, ref):
+    assert res["cycles"] == ref.cycles
+    assert res["bank_conflicts"] == ref.bank_conflicts
+    for name in _COUNTERS:
+        assert res["counters"][name].tolist() == [getattr(c, name) for c in ref.cores], name
+    assert res["finished_at"].tolist() == [c.finished_at for c in ref.cores]
+    assert res["tcdm"] == {a: cl.tcdm.get(a, 0) for a in res["tcdm"]}
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+@pytest.mark.parametrize("n", [2, 4, 8])
+@pytest.mark.parametrize("job", _SCU_JOBS, ids=["-".join(map(str, j)) for j in _SCU_JOBS])
+def test_executors_match_engine_on_scu_jobs(job, n, xp_name):
+    """The SCU phase (comparators, private links, elw sleep and wake, the
+    barrier, mutex and notifier extensions) against the lockstep engine, bit
+    for bit: cycles, the nine counters with ``gated_cycles`` and
+    ``scu_accesses``, conflicts, retire cycles and the final TCDM words."""
+    if xp_name == "jax" and not HAS_JAX:
+        pytest.skip("jax unavailable")
+    from repro.core.scu.trace import run_traces_jax
+
+    run = run_traces_xp if xp_name == "numpy" else run_traces_jax
+    fb = _scu_job(job, n)
+    cl = fb.config.cluster
+    cl.load(fb.config.programs)
+    ref = cl.run()
+    fb = _scu_job(job, n)
+    res = run(fb.config.programs, n_banks=fb.config.cluster.n_banks)
+    _assert_matches_engine(res, cl, ref)
+    assert ref.total_scu > 0 and (job[1] == "scu" or ref.total_tcdm > 0)
+
+
+def _random_scu_traces(seed, n, rounds=4):
+    """Per-lane traces over every SCU op the executor encodes, each round
+    closed by the hardware barrier.  Every response (a read, an elw) is
+    stored in a TCDM word of its own, so the final words show it.  Some
+    rounds line up a lane's read of the barrier or mutex with other lanes'
+    arrivals or unlock in the same cycle, where the lanes' order decides."""
+    import random
+
+    rng = random.Random(seed)
+    kinds = [rng.randrange(3) for _ in range(rounds)]
+    bases = [rng.randrange(1, 7) for _ in range(rounds)]
+    progs = []
+    for cid in range(n):
+        tb = TraceBuilder()
+        slots = iter(range(1_000_000))
+
+        def store(delta=0):
+            tb.mem_delta("sw", 0x1000 + 4 * (cid + n * next(slots)), delta)
+
+        if cid % 2:  # a store at row 0 (idle lanes' rows read as row 0's)
+            store()
+        for r in range(rounds):
+            if kinds[r] == 1:  # barrier reads beside same-cycle arrivals
+                tb.compute(1)
+                if cid % 2 == 0:
+                    tb.scu("read", ("barrier", 0, "status"))
+                    store()
+            elif kinds[r] == 2:  # mutex reads beside its owner's unlock
+                if cid == 0:
+                    tb.scu("elw", ("mutex", 0, "lock"))
+                    store()
+                    tb.compute(1)
+                    tb.scu("write", ("mutex", 0, "unlock"), 77)
+                else:
+                    tb.compute(bases[r] + cid)
+                    tb.scu("read", ("mutex", 0, "status"))
+                    store()
+            for _ in range(rng.randint(1, 3) if kinds[r] == 0 else 0):
+                tb.compute(rng.randint(1, 3))
+                k, e = rng.randrange(8), rng.randrange(8)
+                if k == 0:
+                    tb.scu("write", ("notifier", e, "trigger"),
+                           rng.choice([0, rng.randrange(1, 1 << n)]))
+                elif k == 1:
+                    tb.scu("write", ("buffer", "clear"), rng.randrange(1 << 10))
+                elif k == 2:
+                    tb.scu("read", rng.choice([("buffer", "event"), ("barrier", 0, "status"),
+                                               ("mutex", 0, "status")]))
+                    store()
+                elif k in (3, 4, 7):  # a wait that this lane's own trigger ends
+                    if k == 4:
+                        tb.scu("write", ("mask", "event"), (1 << e) | rng.randrange(1 << 10))
+                    elif k == 7:
+                        tb.scu("write", ("mask", "event"), 0)
+                    tb.scu("write", ("notifier", e, "trigger"), 1 << cid)
+                    tb.scu("elw", ("notifier", e, "wait") if k == 3 else ("event", "wait_any"))
+                    store()
+                elif k == 5:
+                    tb.scu("elw", ("mutex", 0, "lock"))
+                    store()
+                    tb.compute(rng.randint(1, 4))
+                    tb.scu("write", ("mutex", 0, "unlock"), rng.randrange(1, 1000))
+                else:
+                    tb.mem("lw", 0x1000 + 4 * rng.randrange(n))
+                    store(1)
+            tb.scu("elw", ("barrier", 0, "wait_all"))
+            store()
+        progs.append(tb.build(roll=False))
+    return progs
+
+
+@given(seed=st.integers(0, 2**31 - 1))
+@settings(max_examples=15, deadline=None)
+def test_run_traces_xp_matches_engine_on_random_scu_traces(seed):
+    """Every encoded SCU op (masks, buffer clears, targeted and broadcast
+    notifiers, wait-any, reads, mutex messages), in the orders the lanes
+    meet them within a cycle, against the lockstep engine."""
+    for n in (2, 3, 4, 8):
+        cl = Cluster(n_cores=n, scu=SCU(n_cores=n), mode="lockstep")
+        cl.load(_random_scu_traces(seed, n))
+        ref = cl.run(max_cycles=100_000)
+        _assert_matches_engine(run_traces_xp(_random_scu_traces(seed, n), n_banks=2 * n),
+                               cl, ref)
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
+def test_run_traces_jax_matches_numpy_on_random_scu_traces():
+    from repro.core.scu.trace import run_traces_jax
+
+    for seed in (3, 4):
+        ref = run_traces_xp(_random_scu_traces(seed, 4), n_banks=8)
+        got = run_traces_jax(_random_scu_traces(seed, 4), n_banks=8)
+        assert got["cycles"] == ref["cycles"]
+        for name in _COUNTERS:
+            assert got["counters"][name].tolist() == ref["counters"][name].tolist(), name
+        assert got["finished_at"].tolist() == ref["finished_at"].tolist()
+        assert got["tcdm"] == ref["tcdm"]
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
+def test_table_without_scu_rows_carries_no_scu_state():
+    """The SCU state and phase exist only for tables with SCU rows: a
+    pure-TCDM table traces the loop it traced before they existed, 14
+    carried arrays and no ``scu.sync`` scope."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.scu.trace import _execute, _jitted_execute, _pack_tables
+
+    def loop(policy):
+        fb = prep_barrier_bench(policy, 4, sfr=0, iters=2, compiled=True)
+        tab, addrs, scu = _pack_tables(fb.config.programs)
+        args = (tab.astype(np.int32), np.zeros(max(len(addrs), 1), np.int32), np.int32(100))
+        kw = dict(n_banks=8, tas_cycles=3, scu=scu)
+        jaxpr = jax.make_jaxpr(functools.partial(_execute, jnp, **kw))(*args)
+        (w,) = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+        text = _jitted_execute().lower(*args, **kw).as_text(debug_info=True)
+        return scu, len(w.outvars), "scu.sync" in text
+
+    assert loop("sw") == (None, 14, False)
+    scu, carried, scoped = loop("scu")
+    assert scu == (1, 1) and carried > 14 and scoped
 
 
 @pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
@@ -250,16 +433,46 @@ def test_executors_match_engine_on_table1_sw_barrier(xp_name):
         assert res["counters"][name].tolist() == [getattr(c, name) for c in ref.cores]
 
 
-@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
-def test_executors_raise_when_cut_at_max_cycles(xp_name):
-    """Neither executor returns partial counters for an unfinished run."""
+@pytest.mark.parametrize("xp_name,job", [
+    ("numpy", "tcdm"), ("jax", "tcdm"), ("numpy", "scu"), ("jax", "scu"),
+], ids=["numpy", "jax", "numpy-scu", "jax-scu"])
+def test_executors_raise_when_cut_at_max_cycles(xp_name, job):
+    """Neither executor returns partial counters for an unfinished run,
+    also when the cut finds lanes asleep on an elw (an ``scu`` mutex with
+    a long critical section: the waiters sleep through it)."""
     if xp_name == "jax" and not HAS_JAX:
         pytest.skip("jax unavailable")
     from repro.core.scu.trace import run_traces_jax
 
     run = run_traces_xp if xp_name == "numpy" else run_traces_jax
+    if job == "tcdm":
+        progs, n_banks = _tcdm_traces(4), 8
+    else:
+        ref = _scu_job(("mutex", "scu", 10, 0), 4).run_sequential().stats
+        assert ref.total_gated > 0
+        fb = _scu_job(("mutex", "scu", 10, 0), 4)
+        progs, n_banks = fb.config.programs, fb.config.cluster.n_banks
     with pytest.raises(RuntimeError, match="did not finish within 20 cycles"):
-        run(_tcdm_traces(4), n_banks=8, max_cycles=20)
+        run(progs, n_banks=n_banks, max_cycles=20)
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+@pytest.mark.parametrize("policy", ["scu", "sw"])
+def test_executor_counts_its_scu_transactions(xp_name, policy):
+    """One run records one ``scu.sync_ops`` count, the lanes' summed
+    ``scu_accesses``: 0 for a pure-TCDM table."""
+    if xp_name == "jax" and not HAS_JAX:
+        pytest.skip("jax unavailable")
+    from repro import obs
+    from repro.core.scu.trace import run_traces_jax
+
+    run = run_traces_xp if xp_name == "numpy" else run_traces_jax
+    fb = prep_barrier_bench(policy, 4, sfr=0, iters=3, compiled=True)
+    t0 = time.perf_counter()
+    res = run(fb.config.programs, n_banks=fb.config.cluster.n_banks)
+    counts = [e.n for e in obs.events(t0, time.perf_counter()) if e.name == "scu.sync_ops"]
+    assert counts == [int(res["counters"]["scu_accesses"].sum())]
+    assert (counts[0] > 0) == (policy == "scu")
 
 
 _PHASES = ["scu.pack", "scu.stage", "scu.loop", "scu.wait", "scu.readback"]
