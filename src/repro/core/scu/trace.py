@@ -891,10 +891,10 @@ def _pack_tables(programs: Sequence[TraceProgram]):
 
 class _Tables(NamedTuple):
     """The executor's read-only inputs, passed to every phase: the packed
-    table's columns (lane x row), each TCDM address's bank, the lane index
-    and the static sizes.  ``xp`` is the array namespace they live in.
-    ``scu`` is ``(barriers, mutexes)``, or ``None`` for tables without SCU
-    rows, which then carry no SCU state and run no SCU phase."""
+    table's columns (lane x row), each TCDM address's bank, the lane index,
+    the cycle cap and the static sizes.  ``xp`` is the array namespace they
+    live in.  ``scu`` is ``(barriers, mutexes)``, or ``None`` for tables
+    without SCU rows, which then carry no SCU state and run no SCU phase."""
 
     xp: Any
     op_k: Any
@@ -908,6 +908,7 @@ class _Tables(NamedTuple):
     a6: Any
     addr_bank: Any
     lanes: Any
+    max_cycles: Any
     n: int
     n_banks: int
     tas_cycles: int
@@ -1113,6 +1114,9 @@ def _grant(t, s):
 
 @_scoped("scu.account")
 def _account(t, s):
+    """Phase 5: the cycle's accounting, and the same for each of the
+    ``s["jump"]`` quiet cycles that :func:`_quiet_jump` found after it, in
+    which no lane changes state."""
     xp, n = t.xp, t.n
     st = s["st"]
     clocked = st != _X_DONE
@@ -1124,8 +1128,9 @@ def _account(t, s):
         gated = st == _X_SLEEP
         clocked = clocked & ~gated
         wait = clocked & ~act
+    cycles = 1 + s["jump"]
     cnt = s["cnt"]
-    inc = xp.stack([
+    inc = cycles * xp.stack([
         clocked.astype(xp.int32),  # active
         act.astype(xp.int32),  # comp
         wait.astype(xp.int32),  # wait
@@ -1138,8 +1143,9 @@ def _account(t, s):
     else:
         cnt = cnt.at[:5].add(inc)
     s = dict(s)
+    del s["jump"]
     s["cnt"] = cnt
-    s["cycle"] = s["cycle"] + 1
+    s["cycle"] = s["cycle"] + cycles
     return s
 
 
@@ -1157,6 +1163,13 @@ def _onehot(t, idx, size: int):
     return idx[None, :] == t.xp.arange(size)[:, None]
 
 
+def _comparators(s):
+    """Which extensions fire this cycle: each barrier every lane arrived at,
+    the lanes waiting on each mutex, and each free mutex with a waiter."""
+    waiting = s["stamp"] >= 0
+    return s["bar"].all(axis=1), waiting, (s["owner"] < 0) & waiting.any(axis=1)
+
+
 @_scoped("scu.sync")
 def _scu_evaluate(t, s):
     """Phase 0: the extension comparators (the engine's ``SCU.evaluate``),
@@ -1167,9 +1180,7 @@ def _scu_evaluate(t, s):
     mutex event."""
     xp, lanes = t.xp, t.lanes
     bar, stamp, owner = s["bar"], s["stamp"], s["owner"]
-    fire = bar.all(axis=1)
-    waiting = stamp >= 0
-    elect = (owner < 0) & waiting.any(axis=1)
+    fire, waiting, elect = _comparators(s)
     el = xp.argmin(xp.where(waiting, stamp, _I32_MAX), axis=1).astype(xp.int32)
     chosen = elect[:, None] & (lanes[None, :] == el[:, None])
     s = dict(s)
@@ -1266,15 +1277,11 @@ def _scu_service(t, s):
     return s
 
 
-@_scoped("scu.sync")
-def _scu_wake(t, s):
-    """Phase 4: every issued ``elw`` polled against its lane's event buffer
-    (the engine's ``Cluster._wake_one``).  A hit clears the lines waited on
-    and answers with the mutex's message for a mutex, else with the
-    buffer; the lane wakes in ``WAKE_CYCLES``, one fewer if it never
-    slept."""
-    xp, lanes = t.xp, t.lanes
-    st, buf, elw = s["st"], s["ev_buf"], s["elw"]
+def _elw_wait(t, s):
+    """Each lane's pending ``elw``: its op code (-1 off an elw), its
+    instance and the event lines it waits on, which grant it once one is
+    in its buffer (the engine's ``SCU.elw_would_grant``)."""
+    xp, lanes, elw = t.xp, t.lanes, s["elw"]
     row = xp.where(elw, s["pend"], 0)
     code, inst = xp.where(elw, t.a0[lanes, row], -1), t.a1[lanes, row]
     mask = s["ev_mask"]
@@ -1282,6 +1289,19 @@ def _scu_wake(t, s):
                     1 << (EV.NOTIFIER0 + xp.where(code == _S_NTF_WAIT, inst, 0)))
     wait = xp.where(code == _S_BAR_WAIT, _EV_BARRIER_BIT, wait)
     wait = xp.where(code == _S_MTX_LOCK, _EV_MUTEX_BIT, wait)
+    return code, inst, wait
+
+
+@_scoped("scu.sync")
+def _scu_wake(t, s):
+    """Phase 4: every issued ``elw`` polled against its lane's event buffer
+    (the engine's ``Cluster._wake_one``).  A hit clears the lines waited on
+    and answers with the mutex's message for a mutex, else with the
+    buffer; the lane wakes in ``WAKE_CYCLES``, one fewer if it never
+    slept."""
+    xp = t.xp
+    st, buf, elw = s["st"], s["ev_buf"], s["elw"]
+    code, inst, wait = _elw_wait(t, s)
     hit = elw & ((buf & wait) != 0)
     is_mtx = code == _S_MTX_LOCK
     value = xp.where(is_mtx, s["msg"][xp.where(is_mtx, inst, 0)], buf)
@@ -1337,12 +1357,49 @@ def _cycle_step(t, s):
         s = _issue_data(t, s)
     s.pop("fetch", None)
     # Phase 2: arbitration + grants.  Phases 3 and 4: the SCU's links and
-    # elw grants.  Phase 5: accounting.
+    # elw grants.  Then the jump over the quiet cycles that follow, and
+    # phase 5: accounting, for this cycle and those.
     s = _grant(t, s)
     if t.scu:
         s = _scu_service(t, s)
         s = _scu_wake(t, s)
-    s = _account(t, s)
+    return _account(t, _quiet_jump(t, s))
+
+
+@_scoped("scu.jump")
+def _quiet_jump(t, s):
+    """Find the cycles after this one in which no lane can act (the
+    engine's ``Cluster.next_event_bound``), and count down their phase-1
+    ``busy`` and ``wake`` in one update (its ``fast_forward``); phase 5
+    accounts them as ``s["jump"]``.  A lane stays quiet while it is ACTIVE
+    and counts down ``busy``, WAKE and counts down ``wake`` past 1, or
+    asleep on an ``elw`` that nothing grants; a DONE lane bounds nothing.
+    Any other lane, an extension about to fire, or no bounded lane at all
+    gives 0, and the jump never passes the cycle cap.  The same lane-min
+    sets ``s["live"]``, whether some lane has not halted, which the loop
+    condition reads."""
+    xp = t.xp
+    st = s["st"]
+    act = st == _X_ACTIVE
+    # unbounded: _I32_MAX for a halted lane, one less for a live sleeper
+    bound = xp.where(st == _X_DONE, _I32_MAX, xp.where(act, s["busy"], 0))
+    if t.scu:
+        waking = st == _X_WAKE
+        bound = xp.where(waking, s["wake"] - 1, bound)
+        _, _, wait = _elw_wait(t, s)
+        bound = xp.where((st == _X_SLEEP) & ((s["ev_buf"] & wait) == 0), _I32_MAX - 1, bound)
+    least = bound.min()
+    k = xp.where(least >= _I32_MAX - 1, 0, least)
+    if t.scu:
+        fire, _, elect = _comparators(s)
+        k = xp.where(fire.any() | elect.any(), 0, k)
+    k = xp.maximum(xp.minimum(k, t.max_cycles - 1 - s["cycle"]), 0)
+    s = dict(s)
+    s["jump"] = k
+    s["live"] = least < _I32_MAX
+    s["busy"] = xp.where(act, s["busy"] - k, s["busy"])
+    if t.scu:
+        s["wake"] = xp.where(waking, s["wake"] - k, s["wake"])
     return s
 
 
@@ -1357,7 +1414,7 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
     t = _Tables(
         xp, tab[:, :, 0], tab[:, :, 1], tab[:, :, 2], tab[:, :, 3], tab[:, :, 4],
         tab[:, :, 5], tab[:, :, 6], tab[:, :, 7], tab[:, :, 8], addr_bank,
-        xp.arange(n), n, n_banks, tas_cycles, scu,
+        xp.arange(n), max_cycles, n, n_banks, tas_cycles, scu,
     )
     state = {
         "pc": xp.zeros(n, dtype=xp.int32),
@@ -1373,6 +1430,8 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
         "conflicts": xp.zeros((), dtype=xp.int32),
         "fin": xp.full((n,), -1, dtype=xp.int32),
         "cycle": xp.zeros((), dtype=xp.int32),
+        "iters": xp.zeros((), dtype=xp.int32),  # loop iterations
+        "live": xp.ones((), dtype=bool),  # some lane has not halted
         "ctr": xp.full((n, length), -1, dtype=xp.int32),
     }
     if scu:
@@ -1392,20 +1451,23 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
             stamp=xp.full((n_mtx, n), -1, dtype=xp.int32),
         )
     if xp is np:
-        while not np.all(state["st"] == _X_DONE) and state["cycle"] < max_cycles:
+        while state["live"] and state["cycle"] < max_cycles:
             state["fetch"] = np.zeros(n, dtype=bool)
             state = _cycle_step(t, state)
+            state["iters"] = state["iters"] + 1
         return state
     import jax
 
     def cond(s):
-        return (~(s["st"] == _X_DONE).all()) & (s["cycle"] < max_cycles)
+        return s["live"] & (s["cycle"] < max_cycles)
 
     def body(s):
         obs.count("scu.loop_traces")  # runs only while jax traces the body
         s = dict(s)
         s["fetch"] = xp.zeros(n, dtype=bool)
-        return _cycle_step(t, s)
+        s = _cycle_step(t, s)
+        s["iters"] = s["iters"] + 1
+        return s
 
     return jax.lax.while_loop(cond, body, state)
 
@@ -1459,9 +1521,19 @@ def run_traces_xp(
     ``scu.readback``.  The jax loop body counts ``scu.loop_traces`` each
     time it is traced, so once per new shape, and its issue, decode, grant
     and account phases carry the named scopes ``scu.issue``,
-    ``scu.decode``, ``scu.grant`` and ``scu.account``, and the SCU phase of
-    a table with SCU rows ``scu.sync``.  The readback counts the job's SCU
-    transactions as ``scu.sync_ops``.
+    ``scu.decode``, ``scu.grant`` and ``scu.account``, the SCU phase of a
+    table with SCU rows ``scu.sync``, and the jump ``scu.jump``.  The
+    readback counts the job's SCU transactions as ``scu.sync_ops`` and its
+    loop iterations as ``scu.loop_iterations``, fetched with the cycle
+    count and the finish flag in one transfer.
+
+    One loop iteration is one simulated cycle and a jump (:func:`_quiet_jump`)
+    over the quiet cycles after it: while no lane can act -- every live
+    lane counts down a compute span or a wake, or sleeps on an ``elw``
+    nothing grants, and no extension can fire -- it advances the least of
+    those countdowns in one update, as the engine's ``fast_forward`` does,
+    and phase 5 accounts them with the cycle.  So a compute span costs one
+    iteration, not one per cycle; every count is the same.
     """
     with obs.span("scu.run"):
         with obs.span("scu.pack"):
@@ -1497,7 +1569,10 @@ def run_traces_xp(
             if not is_np:
                 jax.block_until_ready(state)
         with obs.span("scu.readback"):
-            if not bool((state["st"] == _X_DONE).all()):
+            scalars = (state["cycle"], state["iters"], state["live"])
+            cycles, iters, live = map(int, scalars if is_np else jax.device_get(scalars))
+            obs.count("scu.loop_iterations", iters)
+            if live:
                 raise RuntimeError(f"traced run did not finish within {max_cycles} cycles")
 
             counters = {
@@ -1506,7 +1581,7 @@ def run_traces_xp(
             }
             obs.count("scu.sync_ops", int(counters["scu_accesses"].sum()))
             return {
-                "cycles": int(state["cycle"]),
+                "cycles": cycles,
                 "counters": counters,
                 "bank_conflicts": int(state["conflicts"]),
                 "finished_at": np.asarray(state["fin"]),

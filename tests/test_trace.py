@@ -14,6 +14,7 @@ of wall clock, and the trace semantics they would exercise are identical to
 the 8-core runs that do cover them.
 """
 
+import functools
 import time
 
 import numpy as np
@@ -376,8 +377,8 @@ def test_run_traces_jax_matches_numpy_on_random_scu_traces():
 @pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
 def test_table_without_scu_rows_carries_no_scu_state():
     """The SCU state and phase exist only for tables with SCU rows: a
-    pure-TCDM table traces the loop it traced before they existed, 14
-    carried arrays and no ``scu.sync`` scope."""
+    pure-TCDM table's loop carries the 14 arrays of the TCDM state, the
+    iteration count and the live flag, and has no ``scu.sync`` scope."""
     import functools
 
     import jax
@@ -395,9 +396,9 @@ def test_table_without_scu_rows_carries_no_scu_state():
         text = _jitted_execute().lower(*args, **kw).as_text(debug_info=True)
         return scu, len(w.outvars), "scu.sync" in text
 
-    assert loop("sw") == (None, 14, False)
+    assert loop("sw") == (None, 16, False)
     scu, carried, scoped = loop("scu")
-    assert scu == (1, 1) and carried > 14 and scoped
+    assert scu == (1, 1) and carried > 16 and scoped
 
 
 @pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
@@ -576,6 +577,126 @@ def test_jax_executor_cache_hits_match_numpy():
         run_traces_jax(p, n_banks=n_banks, max_cycles=100)
     traces = [e for e in obs.events(t0, time.perf_counter()) if e.name == "scu.loop_traces"]
     assert sum(e.n for e in traces) == 1
+
+
+def _counted_run(xp_name, progs, **kw):
+    """One executor call: its result and its ``scu.loop_iterations`` count."""
+    if xp_name == "jax" and not HAS_JAX:
+        pytest.skip("jax unavailable")
+    from repro import obs
+    from repro.core.scu.trace import run_traces_jax
+
+    run = run_traces_xp if xp_name == "numpy" else run_traces_jax
+    t0 = time.perf_counter()
+    res = run(progs, **kw)
+    (iters,) = [e.n for e in obs.events(t0, time.perf_counter()) if e.name == "scu.loop_iterations"]
+    return res, iters
+
+
+def _engine_and_executor(xp_name, prep):
+    """The lockstep engine's run of ``prep()``, and the executor's."""
+    fb = prep()
+    cl = fb.config.cluster
+    cl.load(fb.config.programs)
+    ref = cl.run()
+    fb = prep()
+    res, iters = _counted_run(xp_name, fb.config.programs, n_banks=fb.config.cluster.n_banks)
+    _assert_matches_engine(res, cl, ref)
+    return ref, res, iters
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+@pytest.mark.parametrize("policy,sfr", [("sw", 4000), ("tree4", 1000)])
+def test_executors_jump_fig5_compute_spans(xp_name, policy, sfr):
+    """A Fig. 5 barrier between long compute spans: the loop jumps each span
+    in one iteration, bit for bit against the engine, in at most a tenth
+    as many iterations as cycles."""
+    ref, res, iters = _engine_and_executor(
+        xp_name, lambda: prep_barrier_bench(policy, 8, sfr=sfr, iters=4, compiled=True,
+                                            mode="lockstep"))
+    assert iters <= res["cycles"] / 10
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+def test_executors_step_table1_arbitration_cycle_by_cycle(xp_name):
+    """The Table 1 ``sw`` barrier at SFR 0 contends for its lock in almost
+    every cycle, so the jump almost never engages: at least 98% as many
+    iterations as cycles (the cycles it skips are the few in which every
+    core counts down a branch or the TAS latency at once)."""
+    fb = prep_barrier_bench("sw", 8, sfr=0, iters=64, compiled=True)
+    res, iters = _counted_run(xp_name, fb.config.programs, n_banks=fb.config.cluster.n_banks)
+    assert res["cycles"] == 11016
+    assert 0.98 * res["cycles"] <= iters < res["cycles"]
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+def test_executors_jump_while_waiters_sleep(xp_name):
+    """The SCU mutex with t_crit 10: the waiters sleep through each critical
+    section while the owner computes, and the loop jumps those cycles with
+    the sleepers' ``gated_cycles`` counted as the engine counts them."""
+    ref, res, iters = _engine_and_executor(xp_name, lambda: _scu_job(("mutex", "scu", 10, 0), 8))
+    assert ref.total_gated > 0
+    assert iters < res["cycles"] / 2
+
+
+def _asleep_for_good(n):
+    """Lanes that compute, then sleep on a notifier no lane triggers."""
+    out = []
+    for _ in range(n):
+        tb = TraceBuilder()
+        tb.compute(5)
+        tb.scu("elw", ("notifier", 3, "wait"))
+        out.append(tb.build())
+    return out
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+@pytest.mark.parametrize("job", ["tcdm", "scu", "asleep"])
+def test_cycle_cap_inside_a_compute_span_is_not_passed(xp_name, job):
+    """A cap that falls inside a jumped span (Fig. 5's 4000-cycle compute,
+    the SCU barrier's 42-cycle one) stops the loop at the cap, not past it,
+    and the run still raises.  Lanes asleep for good bound no jump but keep
+    the loop running to the cap: it never takes them for halted."""
+    if xp_name == "jax" and not HAS_JAX:
+        pytest.skip("jax unavailable")
+    from repro.core.scu.trace import _execute, _jitted_execute, _pack_tables, run_traces_jax
+
+    n_banks = 16
+    if job == "tcdm":
+        progs, cap = lambda: prep_barrier_bench("sw", 8, sfr=4000, iters=2,
+                                                compiled=True).config.programs, 2000
+    elif job == "scu":
+        progs, cap = lambda: _scu_job(("barrier", "scu", 0, 42), 8).config.programs, 30
+    else:
+        progs, cap = lambda: _asleep_for_good(8), 30
+    tab, addrs, scu = _pack_tables(progs())
+    addr_bank = ((addrs >> 2) % n_banks).astype(np.int32) if len(addrs) else np.zeros(1, np.int32)
+    args = (tab.astype(np.int32), addr_bank, np.int32(cap))
+    run = functools.partial(_execute, np) if xp_name == "numpy" else _jitted_execute()
+    state = run(*args, n_banks=n_banks, tas_cycles=3, scu=scu)
+    assert int(state["cycle"]) == cap and bool(state["live"])
+    cnt = np.asarray(state["cnt"])
+    assert (cnt[0] + cnt[3]).tolist() == [cap] * 8  # active_cycles + gated_cycles
+    assert int(state["iters"]) < cap
+    if job == "asleep":
+        assert cnt[3].min() > 0
+    run = run_traces_xp if xp_name == "numpy" else run_traces_jax
+    with pytest.raises(RuntimeError, match=f"did not finish within {cap} cycles"):
+        run(progs(), n_banks=n_banks, max_cycles=cap)
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
+@pytest.mark.parametrize("job", [("barrier", "sw", 0, 1000), ("mutex", "scu", 10, 0),
+                                 ("barrier", "scu", 0, 42), ("barrier", "tree_ew", 0, 0)],
+                         ids=["sw-1000", "scu-mutex-10", "scu-42", "tree_ew"])
+def test_numpy_and_jax_count_the_same_iterations(job):
+    counts = []
+    for xp_name in ("numpy", "jax"):
+        fb = _scu_job(job, 8)
+        res, iters = _counted_run(xp_name, fb.config.programs, n_banks=fb.config.cluster.n_banks)
+        counts.append((res["cycles"], iters))
+    assert counts[0] == counts[1]
+    assert counts[0][1] < counts[0][0]
 
 
 def test_executor_rejects_a_cycle_cap_beyond_int32():
