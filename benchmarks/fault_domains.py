@@ -1,11 +1,11 @@
 """Fault-domain chaos sweep: domain fault rate x routing-policy matrix.
 
 A fixed, deterministic stream of 8-core SCU barrier jobs is served by a
-:class:`repro.serve.fleet_pool.FleetPool` of three single-slot fleets --
-three *fault domains*.  Domain 0 is sick: a seeded inject hook arms a
-lost-barrier-wake :class:`repro.core.scu.faults.FaultPlan` on a fraction
-of the configs admitted there (the *domain fault rate*), so any attempt
-that lands in the blast radius deadlocks and burns its whole cycle
+:class:`repro.serve.fleet_service.FleetService` of three single-slot
+fleets -- three *fault domains*.  Domain 0 is sick: a seeded inject hook
+arms a lost-barrier-wake :class:`repro.core.scu.faults.FaultPlan` on a
+fraction of the configs admitted there (the *domain fault rate*), so any
+attempt that lands in the blast radius deadlocks and burns its whole cycle
 budget.  The other domains stay clean.  Three routing policies run the
 identical arrival schedule:
 
@@ -41,8 +41,7 @@ from typing import Dict
 
 from repro.core.scu.faults import FaultEvent, FaultPlan
 from repro.core.scu.programs import prep_barrier_bench
-from repro.serve.fleet_pool import BreakerPolicy, FleetPool
-from repro.serve.fleet_service import RetryPolicy
+from repro.serve.fleet_service import BreakerPolicy, FleetService, RetryPolicy
 
 # pool geometry: three single-slot fault domains, so placement decisions
 # are legible and every domain-0 admission is a countable wasted attempt
@@ -109,7 +108,7 @@ def _run_cell(rate: float, policy: str) -> Dict:
         breaker = BreakerPolicy(probation_after=1, cooldown_rounds=200,
                                 probe_successes=1)
 
-    pool = FleetPool(
+    pool = FleetService(
         n_domains=N_DOMAINS, n_slots=N_SLOTS, slot_cores=SLOT_CORES,
         queue_limit=N_JOBS, retry=retry, breaker=breaker,
         inject=_inject(rate),

@@ -5,11 +5,11 @@ Two deterministic experiments over the checkpoint/restore machinery
 rounds of seeded runs, so every number is bit-exact across machines and
 hard-gated by ``scripts/bench_compare.py``.
 
-**Migration** -- a :class:`repro.serve.fleet_pool.FleetPool` of two
-single-slot domains serves compiled 8-core SCU barrier jobs.  Domain 0 is
-sick: every admission there is armed with a voltage droop that freezes all
-eight cores mid-run, so the attempt burns to its ``max_cycles`` cap and
-times out.  The identical schedule runs twice:
+**Migration** -- a :class:`repro.serve.fleet_service.FleetService` of
+two single-slot fault domains serves compiled 8-core SCU barrier jobs.
+Domain 0 is sick: every admission there is armed with a voltage droop
+that freezes all eight cores mid-run, so the attempt burns to its
+``max_cycles`` cap and times out.  The identical schedule runs twice:
 
 * ``restart`` -- plain reroute: the retry is rebuilt from scratch on the
   healthy domain, so the whole failed attempt (``max_cycles`` cycles) is
@@ -48,7 +48,6 @@ from typing import Dict
 
 from repro.core.scu.faults import FaultEvent, FaultPlan
 from repro.core.scu.programs import prep_barrier_bench
-from repro.serve.fleet_pool import FleetPool
 from repro.serve.fleet_service import (
     CheckpointPolicy,
     FleetService,
@@ -109,7 +108,7 @@ def _factory(attempt: int):
 
 
 def _run_migration_cell(mode: str) -> Dict:
-    pool = FleetPool(
+    pool = FleetService(
         n_domains=N_DOMAINS, n_slots=1, slot_cores=SLOT_CORES,
         retry=RetryPolicy(max_attempts=3, backoff_rounds=0, reroute=True),
         inject=_inject,

@@ -97,7 +97,7 @@ def _serve(benches, arrivals, mode: str):
     i = 0
     guard = 0
     t0 = time.perf_counter()
-    while i < len(benches) or svc.pending or svc.fleet.occupied:
+    while i < len(benches) or svc.pending or svc.active:
         while i < len(benches) and arrivals[i] <= svc.round:
             jobs.append(svc.submit(benches[i][1].config))
             i += 1

@@ -21,7 +21,6 @@ from repro.core.scu import (
 from repro.core.scu.engine import SlotFleet
 from repro.core.scu.faults import FaultEvent, FaultPlan, Watchdog
 from repro.core.scu.programs import prep_barrier_bench
-from repro.serve.fleet_pool import FleetPool
 from repro.serve.fleet_service import (
     CheckpointPolicy,
     FleetService,
@@ -362,7 +361,7 @@ def test_pool_live_migration_beats_restart_reroute():
         return config
 
     def run_pool(ckpt):
-        pool = FleetPool(
+        pool = FleetService(
             n_domains=2, n_slots=1, slot_cores=8,
             retry=RetryPolicy(max_attempts=3, backoff_rounds=0, reroute=True),
             inject=inject, checkpoint=ckpt,
@@ -392,7 +391,7 @@ def test_service_suspend_all_resumes_bit_exact():
             svc.step()
         if suspend_at:
             suspended = svc.suspend_all()
-            assert svc.fleet.occupied == 0
+            assert svc.fleets[0].occupied == 0
             assert all(j.checkpoint is not None for j in suspended)
         svc.run_until_drained()
         return [j.stats for j in jobs]
@@ -402,8 +401,8 @@ def test_service_suspend_all_resumes_bit_exact():
 
 def test_pool_suspend_all_resumes_bit_exact():
     def run(suspend_at):
-        pool = FleetPool(n_domains=2, n_slots=1, slot_cores=8,
-                         checkpoint=CheckpointPolicy(4))
+        pool = FleetService(n_domains=2, n_slots=1, slot_cores=8,
+                            checkpoint=CheckpointPolicy(4))
         jobs = [pool.submit(factory=_factory(iters=64)) for _ in range(3)]
         for _ in range(suspend_at):
             pool.step()

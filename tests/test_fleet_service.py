@@ -26,8 +26,13 @@ from repro.core.scu.programs import (
 from repro.serve.arrivals import bursty_trace, poisson_trace
 from repro.serve.energy import job_energy
 from repro.core.scu.faults import FaultEvent, FaultPlan, Watchdog
-from repro.serve.fleet_pool import BreakerPolicy, DomainHealth, FleetPool
-from repro.serve.fleet_service import FleetService, QueueFull, RetryPolicy
+from repro.serve.fleet_service import (
+    BreakerPolicy,
+    DomainHealth,
+    FleetService,
+    QueueFull,
+    RetryPolicy,
+)
 
 POLICIES = ("scu", "tas", "sw", "tree", "tree4", "tree_ew", "fifo")
 
@@ -76,7 +81,7 @@ def _serve_stream(svc, benches, arrivals, max_rounds=5_000_000):
     jobs = [None] * len(benches)
     i = 0
     rounds = 0
-    while i < len(benches) or svc.pending or svc.fleet.occupied:
+    while i < len(benches) or svc.pending or svc.active:
         while i < len(benches) and arrivals[i] <= svc.round:
             jobs[i] = svc.submit(benches[i].config)
             i += 1
@@ -406,7 +411,7 @@ def test_run_until_drained_terminates_on_permanent_failures():
     assert all(j.state == "failed" and j.failed for j in jobs)
     assert all(j.attempts == 2 and len(j.fault_log) == 2 for j in jobs)
     assert all(j.finished_round is not None for j in jobs)
-    assert not svc.queue and not svc._backoff and not svc.fleet.occupied
+    assert not svc.pending and not svc._backoff and not svc.active
 
 
 def test_retry_recovers_transient_fault():
@@ -545,7 +550,7 @@ def test_backoff_requeue_bypasses_queue_bound():
     ) is None, "the bound must reject fresh submissions"
     while j.state == "backoff":
         svc.step()
-        assert len(svc.queue) <= svc.queue_limit + 1
+        assert svc.pending <= svc.queue_limit + 1
     assert j.state in ("queued", "running", "failed"), \
         "the requeue must have bypassed the full queue"
     svc.run_until_drained()
@@ -681,7 +686,7 @@ def test_tenant_isolation_under_fault_chains(seed):
 
 
 # ---------------------------------------------------------------------------
-# FleetPool: fault domains, health-aware routing, quarantine, reroute
+# Fault domains: health-aware routing, quarantine, reroute
 # ---------------------------------------------------------------------------
 
 
@@ -704,11 +709,11 @@ def _victim_inject(victims):
 
 def test_pool_validation():
     with pytest.raises(ValueError, match="n_domains"):
-        FleetPool(n_domains=0, n_slots=1, slot_cores=8)
+        FleetService(n_domains=0, n_slots=1, slot_cores=8)
     with pytest.raises(ValueError, match="placement"):
-        FleetPool(n_domains=2, n_slots=1, slot_cores=8, placement="random")
+        FleetService(n_domains=2, n_slots=1, slot_cores=8, placement="random")
     with pytest.raises(ValueError, match="queue_limit"):
-        FleetPool(n_domains=2, n_slots=1, slot_cores=8, queue_limit=0)
+        FleetService(n_domains=2, n_slots=1, slot_cores=8, queue_limit=0)
     with pytest.raises(ValueError, match="probation_after"):
         BreakerPolicy(probation_after=0)
     with pytest.raises(ValueError, match="cooldown_rounds"):
@@ -723,21 +728,23 @@ def test_pool_placement_is_deterministic():
     """round-robin cycles domains in index order; least-loaded picks the
     emptiest domain with ties to the lower id -- both pure functions of
     the pool state, no randomness anywhere."""
-    rr = FleetPool(n_domains=3, n_slots=2, slot_cores=8,
-                   placement="round-robin")
+    rr = FleetService(n_domains=3, n_slots=2, slot_cores=8,
+                      placement="round-robin")
     doms = [rr.submit(_clean_factory(1)).domain for _ in range(6)]
     assert doms == [0, 1, 2, 0, 1, 2]
 
-    ll = FleetPool(n_domains=3, n_slots=2, slot_cores=8,
-                   placement="least-loaded")
+    ll = FleetService(n_domains=3, n_slots=2, slot_cores=8,
+                      placement="least-loaded")
     doms = [ll.submit(_clean_factory(1)).domain for _ in range(6)]
     assert doms == [0, 1, 2, 0, 1, 2]  # load ties break to the lower id
 
 
 def test_pool_clean_stream_matches_single_fleet_service():
-    """With one domain and no faults the pool must be indistinguishable
-    from the plain FleetService: same stats, same rounds, same lane
-    accounting -- the new layer adds routing, not scheduling drift."""
+    """With one domain and no faults the service schedules this stream
+    exactly as the single-fleet scheduler did before fault domains were
+    folded into it: pinned per-job admission and finish rounds and cycles,
+    total rounds and lane accounting -- the domain layer adds routing,
+    not scheduling drift.  Stats stay bit-exact against sequential runs."""
     def build():
         return [
             prep_barrier_bench(p, 8, sfr=s, iters=i)
@@ -746,27 +753,57 @@ def test_pool_clean_stream_matches_single_fleet_service():
             )
         ]
 
-    svc = FleetService(n_slots=2, slot_cores=8, queue_limit=16)
-    svc_jobs = [svc.submit(b.config) for b in build()]
-    svc.run_until_drained()
-
-    pool = FleetPool(n_domains=1, n_slots=2, slot_cores=8, queue_limit=16)
-    pool_jobs = [pool.submit(b.config) for b in build()]
+    seq = [b.run_sequential() for b in build()]
+    benches = build()
+    pool = FleetService(n_domains=1, n_slots=2, slot_cores=8, queue_limit=16)
+    pool_jobs = [pool.submit(b.config) for b in benches]
     pool.run_until_drained()
 
-    for a, b in zip(svc_jobs, pool_jobs):
-        assert a.stats == b.stats
-        assert (a.state, a.admitted_round, a.finished_round) == \
-            (b.state, b.admitted_round, b.finished_round)
-    assert svc.round == pool.round
-    assert svc.idle_lane_fraction == pool.idle_lane_fraction
+    assert [
+        (j.state, j.admitted_round, j.finished_round, j.stats.cycles)
+        for j in pool_jobs
+    ] == [
+        ("done", 0, 12, 19),
+        ("done", 0, 445, 642),
+        ("done", 13, 256, 349),
+        ("done", 257, 494, 372),
+    ]
+    for j, b, ref in zip(pool_jobs, benches, seq):
+        assert b.finalize(j.stats) == ref
+    assert pool.round == 495
+    assert pool.idle_lane_fraction == 0.04949494949494948
+
+
+def test_priority_admission_is_per_domain():
+    """Under admission_order="priority" each domain admits the best job of
+    its own queue: highest priority first within the domain, and a
+    higher-priority job queued on another domain never takes its lane."""
+    svc = FleetService(
+        n_domains=2, n_slots=1, slot_cores=8, placement="round-robin",
+        admission_order="priority",
+    )
+    prios = (0, 0, 1, 5, 9, 2)
+    jobs = [svc.submit(_clean_factory(1), priority=p) for p in prios]
+    assert [j.domain for j in jobs] == [0, 1, 0, 1, 0, 1]
+    svc.run_until_drained(max_rounds=500_000)
+    assert all(j.state == "done" for j in jobs)
+
+    def admission_order(d):
+        on_d = [j for j in jobs if j.domain == d]
+        return [j.job_id for j in sorted(on_d, key=lambda j: j.admitted_round)]
+
+    assert admission_order(0) == [4, 2, 0]
+    assert admission_order(1) == [3, 5, 1]
+    # each domain's best job starts in round 0, side by side
+    assert jobs[4].admitted_round == jobs[3].admitted_round == 0
+    assert svc.preemptions == 0
 
 
 def test_pool_fifo_fairness_per_domain():
     """Jobs placed on the same domain are admitted in submission order --
     and a rerouted retry joins the *tail* of its new domain's queue, never
     jumping the fresh submissions already waiting there."""
-    pool = FleetPool(
+    pool = FleetService(
         n_domains=2, n_slots=1, slot_cores=8, placement="round-robin",
         retry=RetryPolicy(max_attempts=2, backoff_rounds=0, reroute=True),
         inject=_victim_inject({0}),
@@ -795,7 +832,7 @@ def test_reroute_completes_jobs_inplace_retry_loses():
     in-place retries (every attempt lands back in the blast radius) while
     reroute=True completes the same job on a healthy domain."""
     def run(reroute):
-        pool = FleetPool(
+        pool = FleetService(
             n_domains=2, n_slots=1, slot_cores=8,
             retry=RetryPolicy(max_attempts=2, backoff_rounds=1,
                               reroute=reroute),
@@ -829,7 +866,7 @@ def test_breaker_walks_the_state_machine():
 
     breaker = BreakerPolicy(probation_after=2, cooldown_rounds=4,
                             probe_successes=2)
-    pool = FleetPool(
+    pool = FleetService(
         n_domains=1, n_slots=2, slot_cores=8, breaker=breaker,
         retry=RetryPolicy(max_attempts=1), inject=inject,
     )
@@ -862,7 +899,7 @@ def test_quarantine_cuts_wasted_cycles_vs_reroute_alone():
     the breaker stops the bleeding after it trips -- strictly fewer wasted
     cycles, same 100% completion."""
     def run(breaker):
-        pool = FleetPool(
+        pool = FleetService(
             n_domains=2, n_slots=1, slot_cores=8,
             retry=RetryPolicy(max_attempts=3, backoff_rounds=0, reroute=True),
             breaker=breaker, inject=_victim_inject({0}),
@@ -900,7 +937,7 @@ def test_watchdog_trip_escalates_to_domain_blame():
         fb.config.cluster.scu.watchdog = Watchdog(timeout=150, mode="raise")
         return fb.config
 
-    pool = FleetPool(
+    pool = FleetService(
         n_domains=2, n_slots=1, slot_cores=8,
         breaker=BreakerPolicy(probation_after=1, cooldown_rounds=10,
                               probe_successes=1),
@@ -927,7 +964,7 @@ def test_pool_backpressure_and_requeue_bypass():
     """The global queue bound rejects fresh submissions (QueueFull /
     try_submit None) but a retry requeue bypasses it -- the pool-level
     twin of the FleetService satellite contract."""
-    pool = FleetPool(
+    pool = FleetService(
         n_domains=2, n_slots=1, slot_cores=8, queue_limit=2,
         retry=RetryPolicy(max_attempts=2, backoff_rounds=3, reroute=True),
         inject=_victim_inject({0}),
