@@ -891,22 +891,16 @@ def _pack_tables(programs: Sequence[TraceProgram]):
 
 class _Tables(NamedTuple):
     """The executor's read-only inputs, passed to every phase: the packed
-    table's columns (lane x row), each TCDM address's bank, the lane index,
-    the cycle cap and the static sizes.  ``xp`` is the array namespace they
-    live in.  ``scu`` is ``(barriers, mutexes)``, or ``None`` for tables
-    without SCU rows, which then carry no SCU state and run no SCU phase."""
+    table once, column-major as ``(10, lanes, rows)`` (the nine packed
+    columns, then the TCDM bank of a memory row's address), the row and
+    lane indices, the cycle cap and the static sizes.
+    ``xp`` is the array namespace they live in.  ``scu`` is ``(barriers,
+    mutexes)``, or ``None`` for tables without SCU rows, which then carry
+    no SCU state and run no SCU phase."""
 
     xp: Any
-    op_k: Any
-    rep_n: Any
-    a0: Any
-    a1: Any
-    a2: Any
-    a3: Any
-    a4: Any
-    a5: Any
-    a6: Any
-    addr_bank: Any
+    tab: Any
+    rows: Any
     lanes: Any
     max_cycles: Any
     n: int
@@ -922,51 +916,75 @@ def _scoped(name: str):
 
     def wrap(fn):
         @functools.wraps(fn)
-        def scoped(t, s):
+        def scoped(t, s, *args):
             if t.xp is np:
-                return fn(t, s)
+                return fn(t, s, *args)
             import jax
 
             with jax.named_scope(name):
-                return fn(t, s)
+                return fn(t, s, *args)
 
         return scoped
 
     return wrap
 
 
-def _set(t, arr, idx, val, mask):
-    # masked scatter: only the masked lanes write.  Unmasked lanes may share
-    # an index with a writer (an idle lane's pending row defaults to row 0),
-    # so they must not write back the old value -- with duplicate indices
-    # that could clobber the real write.
-    if t.xp is np:
-        out = arr.copy()
-        out[idx[mask]] = np.broadcast_to(val, idx.shape)[mask]
-        return out
-    return arr.at[t.xp.where(mask, idx, arr.shape[0])].set(val, mode="drop")
+# The loop body reads and writes by these one-hot selects, never by an
+# indexed gather or scatter (see run_traces_xp).
+
+def _onehot(t, idx, size: int):
+    """``(size, lanes)`` bool: row ``i`` marks the lanes whose ``idx`` is ``i``."""
+    return idx[None, :] == t.xp.arange(size)[:, None]
 
 
-def _add(t, arr, idx, val, mask):
-    # per-lane counter bump: arr[idx[lane], lane] += val[lane] where mask
-    if t.xp is np:
-        out = arr.copy()
-        v = val if np.isscalar(val) else val[mask]
-        np.add.at(out, (idx[mask], t.lanes[mask]), v)
-        return out
-    return arr.at[idx, t.lanes].add(t.xp.where(mask, val, 0))
+def _pick(t, arr, hot):
+    """Each lane's ``arr[idx]``, given ``hot = _onehot(t, idx, len(arr))``:
+    the lane's one marked entry of the first axis, by a masked reduction."""
+    xp = t.xp
+    hot = hot.reshape(hot.shape + (1,) * (arr.ndim - 1))
+    if arr.dtype == bool:
+        return (hot & arr[:, None]).any(axis=0)
+    return xp.where(hot, arr[:, None], 0).sum(axis=0, dtype=arr.dtype)
+
+
+def _row(t, idx):
+    """Each lane's table row ``idx``, as ``(10, lanes)``: row ``c`` holds
+    column ``c`` (op kind, repeat count, ``a0``..``a6``, bank).  One masked
+    sum over the rows fetches every column; ``idx`` must lie in the table."""
+    hot = idx[:, None] == t.rows[None, :]
+    return t.xp.where(hot[None], t.tab, 0).sum(axis=2, dtype=t.tab.dtype)
+
+
+def _set(t, arr, hot, old, val, mask):
+    """``arr[idx] = val`` for the lanes in ``mask``, given ``hot =
+    _onehot(t, idx, len(arr))`` and ``old = _pick(t, arr, hot)``: each entry
+    gains the sum of ``val - old`` over the masked lanes that mark it.  That
+    sum is the one writer's change, as at most one masked lane marks an
+    entry: the writers are the cycle's grants, at most one per bank, and a
+    word lies in one bank.  (int32 wraps, so ``old + (val - old)`` is
+    ``val`` exactly.)"""
+    change = t.xp.where(hot & mask[None, :], val - old, 0)
+    return arr + change.sum(axis=1, dtype=arr.dtype)
+
+
+def _add(t, cnt, row: int, val, mask):
+    """Counter row ``row`` += ``val`` for the lanes in ``mask``, as a dense
+    ``(counters, lanes)`` increment (``cnt.at[row].add`` is a scatter-add
+    on the device even with a static row)."""
+    at = mask[None, :] & (np.arange(cnt.shape[0]) == row)[:, None]
+    return cnt + t.xp.where(at, val, t.xp.int32(0))
 
 
 @_scoped("scu.decode")
-def _decode_step(t, s):
-    """Resolve one control row for every lane that needs a fetch."""
-    xp, lanes = t.xp, t.lanes
-    pc, rep, R, st = s["pc"], s["rep"], s["R"], s["st"]
-    row_k = xp.take_along_axis(t.op_k, pc[:, None], axis=1)[:, 0]
+def _decode_step(t, s, row):
+    """Resolve one control row for every lane that needs a fetch.  ``row``
+    is what :func:`_issue_data` fetched before it: a lane that it moved on
+    fetches no more, so each lane resolved here is still on that row."""
+    xp = t.xp
+    pc, R, st = s["pc"], s["R"], s["st"]
+    row_k, _, r0, r1 = row[:4]
     fetching = s["fetch"] & (st == _X_ACTIVE)
     is_ctrl = fetching & (row_k >= T_JMP)
-    r0 = xp.take_along_axis(t.a0, pc[:, None], axis=1)[:, 0]
-    r1 = xp.take_along_axis(t.a1, pc[:, None], axis=1)[:, 0]
     # JMP
     jmp = is_ctrl & (row_k == T_JMP)
     new_pc = xp.where(jmp, r0, pc)
@@ -976,18 +994,13 @@ def _decode_step(t, s):
     # LOOP: per-(lane, row) counters; -1 = not armed yet
     lp = is_ctrl & (row_k == T_LOOP)
     ctr = s["ctr"]
-    cur = xp.take_along_axis(ctr, pc[:, None], axis=1)[:, 0]
+    at_pc = pc[:, None] == t.rows[None, :]
+    cur = xp.where(at_pc, ctr, 0).sum(axis=1, dtype=ctr.dtype)
     cur = xp.where(cur < 0, r1, cur)
     take = lp & (cur > 0)
     new_pc = xp.where(lp, xp.where(cur > 0, r0, pc + 1), new_pc)
     new_ctr_val = xp.where(take, cur - 1, -1)
-    if xp is np:
-        ctr = ctr.copy()
-        ctr[lanes[lp], pc[lp]] = new_ctr_val[lp]
-    else:
-        ctr = ctr.at[lanes, pc].set(
-            xp.where(lp, new_ctr_val, ctr[lanes, pc])
-        )
+    ctr = xp.where(at_pc & lp[:, None], new_ctr_val[:, None], ctr)
     # HALT
     halt = is_ctrl & (row_k == T_HALT)
     st = xp.where(halt, _X_DONE, st)
@@ -999,21 +1012,19 @@ def _decode_step(t, s):
 
 
 @_scoped("scu.issue")
-def _issue_data(t, s):
-    """Lanes whose pc sits on a data row: issue it (instr, busy/stall)."""
-    xp, n = t.xp, t.n
+def _issue_data(t, s, row):
+    """Lanes whose pc sits on a data row: issue it (instr, busy/stall).
+    ``row`` is each lane's row at ``pc``, :func:`_row`."""
+    xp = t.xp
     pc, rep = s["pc"], s["rep"]
     fetch = s["fetch"]
-    row_k = xp.take_along_axis(t.op_k, pc[:, None], axis=1)[:, 0]
+    row_k, rn, c0, _, d_imm, d_flag = row[:6]
     data = fetch & (row_k <= T_SCU)
-    rn = xp.take_along_axis(t.rep_n, pc[:, None], axis=1)[:, 0]
     r = xp.where(rep > 0, rep, rn) - 1
     new_pc = xp.where(data & (r == 0), pc + 1, pc)
     new_rep = xp.where(data, r, rep)
-    cnt = s["cnt"]
-    cnt = _add(t, cnt, 5 * xp.ones(n, dtype=xp.int32), 1, data)  # instructions
+    cnt = _add(t, s["cnt"], 5, 1, data)  # instructions
     # COMPUTE: busy = max(0, c - 1), stay ACTIVE
-    c0 = xp.take_along_axis(t.a0, pc[:, None], axis=1)[:, 0]
     comp = data & (row_k == T_COMPUTE)
     busy = xp.where(comp, xp.maximum(c0 - 1, 0), s["busy"])
     # MEM / POLL: pend at the issuing row, STALL; delta stores latch now
@@ -1025,8 +1036,6 @@ def _issue_data(t, s):
         scu = data & (row_k == T_SCU)
         st = xp.where(scu, _X_SCU, st)
         pend = xp.where(scu, pc, pend)
-    d_imm = xp.take_along_axis(t.a2, pc[:, None], axis=1)[:, 0]
-    d_flag = xp.take_along_axis(t.a3, pc[:, None], axis=1)[:, 0]
     pdata = xp.where(
         data & (row_k == T_MEM),
         xp.where(d_flag == 1, s["R"] + d_imm, d_imm),
@@ -1042,56 +1051,37 @@ def _issue_data(t, s):
 @_scoped("scu.grant")
 def _grant(t, s):
     """Per-bank round-robin arbitration + transaction effects."""
-    xp, lanes, n, n_banks, tas_cycles = t.xp, t.lanes, t.n, t.n_banks, t.tas_cycles
-    st, pend = s["st"], s["pend"]
+    xp, lanes, n, tas_cycles = t.xp, t.lanes, t.n, t.tas_cycles
+    st, pend, rr = s["st"], s["pend"], s["rr"]
     req = st == _X_STALL
-    p_row = xp.where(req, pend, 0)
-    r_kind = t.op_k[lanes, p_row]  # T_MEM / T_POLL
-    m_kind = t.a0[lanes, p_row]
-    aidx = t.a1[lanes, p_row]
-    bank = t.addr_bank[aidx]
-    key = (lanes - s["rr"][bank]) % n
+    r_kind, _, m_kind, aidx, until, hit_c, miss_c, hit_i, miss_i, bank = _row(
+        t, xp.where(req, pend, 0))  # T_MEM / T_POLL rows
+    # each bank grants its request of least key, the lanes counted from
+    # the bank's round-robin pointer; keys differ, so one lane per bank
     big = n + 1
-    kmat = xp.where(
-        req[None, :] & (bank[None, :] == xp.arange(n_banks)[:, None]),
-        key[None, :], big,
-    )
-    wlane = xp.argmin(kmat, axis=1)
-    has = kmat[xp.arange(n_banks), wlane] < big
-    win = xp.zeros(n, dtype=bool)
-    if xp is np:
-        win = win.copy()
-        win[wlane[has]] = True
-    else:
-        # scatter-add, not set: banks with no requester still argmin to
-        # lane 0 with has=False, and a duplicate-index set could let
-        # that clobber lane 0's real grant
-        win = xp.zeros(n, dtype=xp.int32).at[wlane].add(
-            has.astype(xp.int32)
-        ) > 0
-    conflicts = s["conflicts"] + req.sum() - has.sum()
-    rr = _set(t, s["rr"], xp.arange(n_banks), (wlane + 1) % n, has)
+    kmat = xp.where(req[None, :] & _onehot(t, bank, t.n_banks),
+                    (lanes[None, :] - rr[:, None]) % n, big)
+    least = kmat.min(axis=1)
+    has = least < big
+    win = (has[:, None] & (kmat == least[:, None])).any(axis=0)
+    conflicts = s["conflicts"] + (req & ~win)  # each lane's lost requests
+    rr = xp.where(has, (rr + least + 1) % n, rr)  # past the granted lane
     # effects
-    cnt = s["cnt"]
-    cnt = _add(t, cnt, 6 * xp.ones(n, dtype=xp.int32), 1, win)  # tcdm
-    val = s["tcdm"][aidx]
+    cnt = _add(t, s["cnt"], 6, 1, win)  # tcdm
+    at_addr = _onehot(t, aidx, s["tcdm"].shape[0])
+    val = _pick(t, s["tcdm"], at_addr)
     is_poll = win & (r_kind == T_POLL)
     is_tas = win & (m_kind == _MK_TAS)
-    cnt = _add(t, cnt, 7 * xp.ones(n, dtype=xp.int32), 1, is_tas)  # tas
-    # tas (Mem or Poll) writes -1 and pays the 3-cycle latency
-    tcdm = _set(t, s["tcdm"], aidx, -1, is_tas)
+    cnt = _add(t, cnt, 7, 1, is_tas)  # tas
+    # tas (Mem or Poll) pays the 3-cycle latency
     base = xp.where(is_tas, tas_cycles - 1, 0)
     # Poll: hit vs miss
-    until = t.a2[lanes, p_row]
-    hit_c, miss_c = t.a3[lanes, p_row], t.a4[lanes, p_row]
-    hit_i, miss_i = t.a5[lanes, p_row], t.a6[lanes, p_row]
     hit = is_poll & (val == until)
     miss = is_poll & (val != until)
     busy = s["busy"]
     busy = xp.where(hit, base + hit_c, busy)
     busy = xp.where(miss, base + miss_c, busy)
-    cnt = _add(t, cnt, 5 * xp.ones(n, dtype=xp.int32),
-               xp.where(hit, hit_i, miss_i), is_poll)
+    cnt = _add(t, cnt, 5, xp.where(hit, hit_i, miss_i), is_poll)
     R = xp.where(hit, val, s["R"])
     # plain Mem
     is_lw = win & (r_kind == T_MEM) & (m_kind == _MK_LW)
@@ -1099,9 +1089,10 @@ def _grant(t, s):
     is_mtas = win & (r_kind == T_MEM) & (m_kind == _MK_TAS)
     R = xp.where(is_lw | is_mtas, val, R)
     R = xp.where(is_sw, 0, R)
-    tcdm = _set(t, tcdm, aidx, s["pdata"], is_sw)
+    # tas (Mem or Poll) writes -1, a store its latched data
+    tcdm = _set(t, s["tcdm"], at_addr, val, xp.where(is_tas, -1, s["pdata"]),
+                is_tas | is_sw)
     busy = xp.where(is_mtas, tas_cycles - 1, busy)
-    busy = xp.where(is_lw | is_sw, busy, busy)
     # resolution: winners go ACTIVE; polls stay armed on a miss
     done_req = win & ~miss
     pend = xp.where(done_req, -1, pend)
@@ -1117,12 +1108,12 @@ def _account(t, s):
     """Phase 5: the cycle's accounting, and the same for each of the
     ``s["jump"]`` quiet cycles that :func:`_quiet_jump` found after it, in
     which no lane changes state."""
-    xp, n = t.xp, t.n
-    st = s["st"]
+    xp, st = t.xp, s["st"]
     clocked = st != _X_DONE
     act = st == _X_ACTIVE
     stall = st == _X_STALL
     wait = stall
+    gated = xp.zeros_like(act)
     if t.scu:
         # a sleeping lane is clock gated; STALL_SCU and WAKE wait clocked
         gated = st == _X_SLEEP
@@ -1130,18 +1121,9 @@ def _account(t, s):
         wait = clocked & ~act
     cycles = 1 + s["jump"]
     cnt = s["cnt"]
-    inc = cycles * xp.stack([
-        clocked.astype(xp.int32),  # active
-        act.astype(xp.int32),  # comp
-        wait.astype(xp.int32),  # wait
-        gated.astype(xp.int32) if t.scu else xp.zeros(n, dtype=xp.int32),  # gated
-        stall.astype(xp.int32),  # stall
-    ])
-    if xp is np:
-        cnt = cnt.copy()
-        cnt[:5] += inc
-    else:
-        cnt = cnt.at[:5].add(inc)
+    # counter rows 0-4: active, comp, wait, gated, stall
+    for row, mask in enumerate((clocked, act, wait, gated, stall)):
+        cnt = _add(t, cnt, row, cycles, mask)
     s = dict(s)
     del s["jump"]
     s["cnt"] = cnt
@@ -1156,11 +1138,6 @@ def _or_over(t, x, axis: int):
     import jax
 
     return jax.lax.reduce(x, np.int32(0), jax.lax.bitwise_or, (axis,))
-
-
-def _onehot(t, idx, size: int):
-    """``(size, lanes)`` bool: row ``i`` marks the lanes whose ``idx`` is ``i``."""
-    return idx[None, :] == t.xp.arange(size)[:, None]
 
 
 def _comparators(s):
@@ -1218,13 +1195,12 @@ def _scu_service(t, s):
     it takes the lanes in order.  A write or read completes (the lane
     resumes next cycle with the read's value, 0 after a write); an ``elw``
     triggers its extension once, arms its sleep entry and waits."""
-    xp, n, lanes = t.xp, t.n, t.lanes
+    xp, lanes = t.xp, t.lanes
     n_bar, n_mtx = t.scu
     st, pend, buf = s["st"], s["pend"], s["ev_buf"]
     fresh = (st == _X_SCU) & ~s["elw"]
-    row = xp.where(fresh, pend, 0)
-    code = xp.where(fresh, t.a0[lanes, row], -1)
-    inst, data = t.a1[lanes, row], t.a2[lanes, row]
+    code, inst, data = _row(t, xp.where(fresh, pend, 0))[2:5]
+    code = xp.where(fresh, code, -1)
     earlier = lanes[:, None] < lanes[None, :]  # [sender, lane]: sender first
     # notifier triggers: event ``inst`` to the lanes set in ``data`` (0:
     # all).  A lane clearing or reading its own buffer sees the triggers of
@@ -1238,8 +1214,8 @@ def _scu_service(t, s):
     buf = xp.where(code == _S_CLEAR, (seen & ~data) | after, seen | after)
     # barrier arrivals; a status read sees the arrivals of earlier lanes
     arrive = _onehot(t, inst, n_bar) & (code == _S_BAR_WAIT)[None, :]
-    b = xp.where(code == _S_RD_BAR, inst, 0)
-    status = s["bar"][b] | (arrive[b] & earlier.T)  # [lane, core]
+    at_bar = _onehot(t, xp.where(code == _S_RD_BAR, inst, 0), n_bar)
+    status = _pick(t, s["bar"], at_bar) | (_pick(t, arrive, at_bar) & earlier.T)  # [lane, core]
     # (barrier reads are refused above 31 lanes, so no shift passes 30)
     bar_word = xp.where(status, 1 << xp.minimum(lanes, 30)[None, :], 0).sum(axis=1, dtype=xp.int32)
     # mutexes: a lock joins the queue stamped with this cycle unless the
@@ -1251,8 +1227,9 @@ def _scu_service(t, s):
     join = on_mtx & (code == _S_MTX_LOCK)[None, :] & (stamp < 0) & (owner[:, None] != lanes[None, :])
     rel = on_mtx & (code == _S_MTX_UNLOCK)[None, :] & (owner[:, None] == lanes[None, :])
     freed = rel.any(axis=1)
-    m = xp.where(code == _S_RD_MTX, inst, 0)
-    held = (owner[m] >= 0) & ~(freed[m] & (owner[m] < lanes))
+    at_mtx = _onehot(t, xp.where(code == _S_RD_MTX, inst, 0), n_mtx)
+    owned_by = _pick(t, owner, at_mtx)
+    held = (owned_by >= 0) & ~(_pick(t, freed, at_mtx) & (owned_by < lanes))
     value = xp.where(code == _S_RD_BUF, seen, 0)
     value = xp.where(code == _S_RD_BAR, bar_word, value)
     value = xp.where(code == _S_RD_MTX, held.astype(xp.int32), value)
@@ -1272,39 +1249,32 @@ def _scu_service(t, s):
         pend=xp.where(done, -1, pend),
         elw=s["elw"] | is_elw,
         sleep_entry=xp.where(is_elw, Cluster.SLEEP_ENTRY_CYCLES, s["sleep_entry"]),
-        cnt=_add(t, s["cnt"], 8 * xp.ones(n, dtype=xp.int32), 1, fresh),  # scu
+        cnt=_add(t, s["cnt"], 8, 1, fresh),  # scu
     )
     return s
-
-
-def _elw_wait(t, s):
-    """Each lane's pending ``elw``: its op code (-1 off an elw), its
-    instance and the event lines it waits on, which grant it once one is
-    in its buffer (the engine's ``SCU.elw_would_grant``)."""
-    xp, lanes, elw = t.xp, t.lanes, s["elw"]
-    row = xp.where(elw, s["pend"], 0)
-    code, inst = xp.where(elw, t.a0[lanes, row], -1), t.a1[lanes, row]
-    mask = s["ev_mask"]
-    wait = xp.where(code == _S_EV_WAIT, xp.where(mask != 0, mask, -1),
-                    1 << (EV.NOTIFIER0 + xp.where(code == _S_NTF_WAIT, inst, 0)))
-    wait = xp.where(code == _S_BAR_WAIT, _EV_BARRIER_BIT, wait)
-    wait = xp.where(code == _S_MTX_LOCK, _EV_MUTEX_BIT, wait)
-    return code, inst, wait
 
 
 @_scoped("scu.sync")
 def _scu_wake(t, s):
     """Phase 4: every issued ``elw`` polled against its lane's event buffer
-    (the engine's ``Cluster._wake_one``).  A hit clears the lines waited on
-    and answers with the mutex's message for a mutex, else with the
+    (the engine's ``Cluster._wake_one``).  A lane waits on the event lines
+    of its ``elw`` (the engine's ``SCU.elw_would_grant``); a hit clears
+    them and answers with the mutex's message for a mutex, else with the
     buffer; the lane wakes in ``WAKE_CYCLES``, one fewer if it never
-    slept."""
+    slept.  The lines waited on are left in ``s["elw_wait"]`` for
+    :func:`_quiet_jump`: a lane still asleep waits on the same ones."""
     xp = t.xp
-    st, buf, elw = s["st"], s["ev_buf"], s["elw"]
-    code, inst, wait = _elw_wait(t, s)
+    st, buf, elw, mask = s["st"], s["ev_buf"], s["elw"], s["ev_mask"]
+    code, inst = _row(t, xp.where(elw, s["pend"], 0))[2:4]
+    code = xp.where(elw, code, -1)
+    wait = xp.where(code == _S_EV_WAIT, xp.where(mask != 0, mask, -1),
+                    1 << (EV.NOTIFIER0 + xp.where(code == _S_NTF_WAIT, inst, 0)))
+    wait = xp.where(code == _S_BAR_WAIT, _EV_BARRIER_BIT, wait)
+    wait = xp.where(code == _S_MTX_LOCK, _EV_MUTEX_BIT, wait)
     hit = elw & ((buf & wait) != 0)
     is_mtx = code == _S_MTX_LOCK
-    value = xp.where(is_mtx, s["msg"][xp.where(is_mtx, inst, 0)], buf)
+    msg = _pick(t, s["msg"], _onehot(t, xp.where(is_mtx, inst, 0), t.scu[1]))
+    value = xp.where(is_mtx, msg, buf)
     woken = Cluster.WAKE_CYCLES - (st == _X_SCU).astype(xp.int32)
     s = dict(s)
     s.update(
@@ -1314,12 +1284,13 @@ def _scu_wake(t, s):
         wake=xp.where(hit, woken, s["wake"]),
         st=xp.where(hit, _X_WAKE, st),
         elw=elw & ~hit,
+        elw_wait=wait,
     )
     return s
 
 
 def _cycle_step(t, s):
-    xp, n = t.xp, t.n
+    xp = t.xp
     if t.scu:
         # Phase 0: comparators; then phase 1 of the lanes on an elw
         s = _scu_evaluate(t, s)
@@ -1335,26 +1306,28 @@ def _cycle_step(t, s):
     s["busy"] = xp.where(counting, busy - 1, busy)
     reissue = advancing & (pend >= 0)
     s["st"] = xp.where(reissue, _X_STALL, st)
-    s["cnt"] = _add(t, s["cnt"], 5 * xp.ones(n, dtype=xp.int32), 1, reissue)
+    s["cnt"] = _add(t, s["cnt"], 5, 1, reissue)
     s["fetch"] = advancing & (pend < 0)
-    # decode until every fetching lane reached a data op or halted
+    # decode until every fetching lane reached a data op or halted; one
+    # row fetch serves an issue and the decode after it
     if xp is np:
         while bool(np.any(s["fetch"])):
-            s = _issue_data(t, s)
+            row = _row(t, s["pc"])
+            s = _issue_data(t, s, row)
             if not bool(np.any(s["fetch"])):
                 break
-            s = _decode_step(t, s)
+            s = _decode_step(t, s, row)
     else:
         import jax
 
         def body(ss):
-            ss = _issue_data(t, ss)
-            return _decode_step(t, ss)
+            row = _row(t, ss["pc"])
+            return _decode_step(t, _issue_data(t, ss, row), row)
 
         s = jax.lax.while_loop(
             lambda ss: ss["fetch"].any(), body, s,
         )
-        s = _issue_data(t, s)
+        s = _issue_data(t, s, _row(t, s["pc"]))
     s.pop("fetch", None)
     # Phase 2: arbitration + grants.  Phases 3 and 4: the SCU's links and
     # elw grants.  Then the jump over the quiet cycles that follow, and
@@ -1375,10 +1348,12 @@ def _quiet_jump(t, s):
     and counts down ``busy``, WAKE and counts down ``wake`` past 1, or
     asleep on an ``elw`` that nothing grants; a DONE lane bounds nothing.
     Any other lane, an extension about to fire, or no bounded lane at all
-    gives 0, and the jump never passes the cycle cap.  The same lane-min
-    sets ``s["live"]``, whether some lane has not halted, which the loop
-    condition reads."""
+    gives 0, and the jump never passes the cycle cap.  A sleeper waits on
+    the event lines :func:`_scu_wake` left in ``s["elw_wait"]``.  The same
+    lane-min sets ``s["live"]``, whether some lane has not halted, which
+    the loop condition reads."""
     xp = t.xp
+    s = dict(s)
     st = s["st"]
     act = st == _X_ACTIVE
     # unbounded: _I32_MAX for a halted lane, one less for a live sleeper
@@ -1386,7 +1361,7 @@ def _quiet_jump(t, s):
     if t.scu:
         waking = st == _X_WAKE
         bound = xp.where(waking, s["wake"] - 1, bound)
-        _, _, wait = _elw_wait(t, s)
+        wait = s.pop("elw_wait")
         bound = xp.where((st == _X_SLEEP) & ((s["ev_buf"] & wait) == 0), _I32_MAX - 1, bound)
     least = bound.min()
     k = xp.where(least >= _I32_MAX - 1, 0, least)
@@ -1394,7 +1369,6 @@ def _quiet_jump(t, s):
         fire, _, elect = _comparators(s)
         k = xp.where(fire.any() | elect.any(), 0, k)
     k = xp.maximum(xp.minimum(k, t.max_cycles - 1 - s["cycle"]), 0)
-    s = dict(s)
     s["jump"] = k
     s["live"] = least < _I32_MAX
     s["busy"] = xp.where(act, s["busy"] - k, s["busy"])
@@ -1407,14 +1381,16 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
              scu: Optional[Tuple[int, int]]):
     """Run the packed int32 table ``(lanes, rows, 9)`` from a cleared
     cluster until every lane halts or ``max_cycles`` cycles pass; the final
-    state.  ``addr_bank`` is each TCDM address's bank; ``scu`` is
+    state.  ``addr_bank`` is each TCDM address's bank, looked up once here
+    for every memory row, so the loop reads it with the row; ``scu`` is
     :func:`_pack_tables`'s SCU shape.  Under jax this is the body of
     :func:`_jitted_execute`, so the tables are its inputs."""
     n, length, _ = tab.shape
+    mem = (tab[:, :, 0] == T_MEM) | (tab[:, :, 0] == T_POLL)
+    row_bank = addr_bank[xp.where(mem, tab[:, :, 3], 0)]
     t = _Tables(
-        xp, tab[:, :, 0], tab[:, :, 1], tab[:, :, 2], tab[:, :, 3], tab[:, :, 4],
-        tab[:, :, 5], tab[:, :, 6], tab[:, :, 7], tab[:, :, 8], addr_bank,
-        xp.arange(n), max_cycles, n, n_banks, tas_cycles, scu,
+        xp, xp.concatenate([xp.transpose(tab, (2, 0, 1)), row_bank[None]]),
+        xp.arange(length), xp.arange(n), max_cycles, n, n_banks, tas_cycles, scu,
     )
     state = {
         "pc": xp.zeros(n, dtype=xp.int32),
@@ -1427,7 +1403,7 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
         "tcdm": xp.zeros(addr_bank.shape[0], dtype=xp.int32),
         "rr": xp.zeros(n_banks, dtype=xp.int32),
         "cnt": xp.zeros((len(_COUNTERS), n), dtype=xp.int32),
-        "conflicts": xp.zeros((), dtype=xp.int32),
+        "conflicts": xp.zeros(n, dtype=xp.int32),
         "fin": xp.full((n,), -1, dtype=xp.int32),
         "cycle": xp.zeros((), dtype=xp.int32),
         "iters": xp.zeros((), dtype=xp.int32),  # loop iterations
@@ -1534,6 +1510,17 @@ def run_traces_xp(
     those countdowns in one update, as the engine's ``fast_forward`` does,
     and phase 5 accounts them with the cycle.  So a compute span costs one
     iteration, not one per cycle; every count is the same.
+
+    The loop body holds no gather and no scatter: each is a device kernel
+    of its own, where a compare-and-select over these small arrays fuses
+    with the arithmetic around it.  A phase fetches its lanes' table rows
+    with one one-hot select over the rows (:func:`_row`), looks up a word,
+    bank pointer or extension by a one-hot reduction (:func:`_pick`), and
+    writes the TCDM and the counters densely (:func:`_set`, :func:`_add`).
+    A dense write has no conflicts: at most one lane writes a TCDM word in
+    a cycle, since each bank grants one lane and a word lies in one bank.
+    Its cost grows as lanes x rows a cycle, which is small for the paper's
+    clusters.
     """
     with obs.span("scu.run"):
         with obs.span("scu.pack"):
@@ -1555,7 +1542,7 @@ def run_traces_xp(
         with obs.span("scu.stage"):
             tab = tab_np.astype(np.int32)
             addr_bank = ((addrs_np >> 2) % n_banks).astype(np.int32)
-            if not len(addr_bank):  # no TCDM word: the grant phase still gathers one
+            if not len(addr_bank):  # no TCDM word: the grant phase still selects one
                 addr_bank = np.zeros(1, dtype=np.int32)
             if not is_np:
                 import jax
@@ -1583,7 +1570,7 @@ def run_traces_xp(
             return {
                 "cycles": cycles,
                 "counters": counters,
-                "bank_conflicts": int(state["conflicts"]),
+                "bank_conflicts": int(np.asarray(state["conflicts"]).sum()),
                 "finished_at": np.asarray(state["fin"]),
                 "tcdm": dict(zip(addrs_np.tolist(), np.asarray(state["tcdm"]).tolist())),
             }

@@ -184,22 +184,43 @@ def _tcdm_traces(n, base=0):
     return out
 
 
-def test_run_traces_xp_matches_engine():
+def _ragged_contended_traces(n):
+    """Pure-TCDM traces whose lengths differ by far more than 4x (lane 0
+    has 5 rows, lane ``n - 1`` has ``15 n - 10``; the shorter tables are
+    padded with HALT rows), in which the lanes contend for one
+    lock word every cycle: a tas, a delta store and a load on it.  First
+    the other lanes poll a flag in another bank, which lane 0 sets after
+    its round on the lock, so their polls miss until then."""
+    out = []
+    for cid in range(n):
+        tb = TraceBuilder()
+        if cid:
+            tb.poll("lw", 0x44, 1, 1, 2)
+        for _ in range(1 + 5 * cid):
+            tb.mem("tas", 0x40)
+            tb.mem_delta("sw", 0x40, 1 + cid)
+            tb.mem("lw", 0x40)
+        if not cid:
+            tb.mem("sw", 0x44, 1)
+        out.append(tb.build(label=f"ragged:{cid}", roll=False))
+    return out
+
+
+_TRACE_SETS = {"tcdm": _tcdm_traces, "ragged-contended": _ragged_contended_traces}
+
+
+@pytest.mark.parametrize("traces", tuple(_TRACE_SETS))
+def test_run_traces_xp_matches_engine(traces):
     """The batched array executor reimplements TCDM issue/arbitration/
     accounting from scratch; it must agree with the engine counter for
-    counter, cycle for cycle."""
+    counter, cycle for cycle, down to the retire cycles and TCDM words."""
     n = 8
+    make = _TRACE_SETS[traces]
     cl = Cluster(n_cores=n, scu=SCU(n_cores=n), mode="lockstep")
-    cl.load(_tcdm_traces(n))
+    cl.load(make(n))
     ref = cl.run()
 
-    res = run_traces_xp(_tcdm_traces(n), n_banks=cl.n_banks)
-    assert res["cycles"] == ref.cycles
-    assert res["bank_conflicts"] == ref.bank_conflicts
-    for i, name in enumerate(_COUNTERS):
-        got = res["counters"][name].tolist()
-        want = [getattr(c, name) for c in ref.cores]
-        assert got == want, name
+    _assert_matches_engine(run_traces_xp(make(n), n_banks=cl.n_banks), cl, ref)
 
 
 def test_run_traces_xp_is_single_use():
@@ -402,16 +423,50 @@ def test_table_without_scu_rows_carries_no_scu_state():
 
 
 @pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
-def test_run_traces_jax_matches_numpy():
+@pytest.mark.parametrize("policy", ["sw", "scu"])
+def test_loop_body_has_no_gather_or_scatter(policy):
+    """Every read and write of the loop body is a dense one-hot select: the
+    outer cycle loop and the decode loop nested in it hold no gather and no
+    scatter, each of which is a device kernel of its own."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.scu.trace import _execute, _pack_tables
+
+    fb = prep_barrier_bench(policy, 8, sfr=0, iters=2, compiled=True)
+    tab, addrs, scu = _pack_tables(fb.config.programs)
+    args = (tab.astype(np.int32), np.zeros(max(len(addrs), 1), np.int32), np.int32(100))
+    kw = dict(n_banks=16, tas_cycles=3, scu=scu)
+    jaxpr = jax.make_jaxpr(functools.partial(_execute, jnp, **kw))(*args)
+    (outer,) = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    seen = []
+
+    def walk(eqn):
+        seen.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                for e in getattr(sub, "eqns", ()):
+                    walk(e)
+
+    walk(outer)
+    assert seen.count("while") == 2  # the cycle loop and the decode loop
+    assert not [p for p in seen if p.startswith(("gather", "scatter"))]
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
+@pytest.mark.parametrize("traces", tuple(_TRACE_SETS))
+def test_run_traces_jax_matches_numpy(traces):
     from repro.core.scu.trace import run_traces_jax
 
-    n = 4
-    ref = run_traces_xp(_tcdm_traces(n), n_banks=2 * n)
-    got = run_traces_jax(_tcdm_traces(n), n_banks=2 * n)
+    n = 4 if traces == "tcdm" else 8
+    ref = run_traces_xp(_TRACE_SETS[traces](n), n_banks=2 * n)
+    got = run_traces_jax(_TRACE_SETS[traces](n), n_banks=2 * n)
     assert got["cycles"] == ref["cycles"]
     assert got["bank_conflicts"] == ref["bank_conflicts"]
     for name in _COUNTERS:
         assert got["counters"][name].tolist() == ref["counters"][name].tolist()
+    assert got["finished_at"].tolist() == ref["finished_at"].tolist()
     assert got["tcdm"] == ref["tcdm"]
 
 
