@@ -237,6 +237,11 @@ def capture_cluster(cluster: Cluster) -> MemberCheckpoint:
     cores = cluster.cores
     if not cores:
         raise NotCheckpointable("cluster has no loaded program")
+    if cluster.hierarchy is not None:
+        raise NotCheckpointable(
+            "a hierarchical cluster's response countdowns are not "
+            "checkpointed -- falling back to restart"
+        )
     for c in cores:
         if not getattr(c.gen, "_is_trace_cursor", False):
             raise NotCheckpointable(

@@ -25,6 +25,31 @@ described in Sec. 4/5 and Fig. 4 of the paper.
 Accounting distinguishes *active* core cycles (clock enabled) from *gated*
 cycles -- the quantity behind the paper's energy results.
 
+A cluster may instead sit behind a MemPool-style hierarchical interconnect
+(Riedel et al., arXiv 2303.17742), described by a :class:`Hierarchy`:
+
+  * core ``c`` sits in tile ``c // cores_per_tile``; bank
+    ``b = (addr >> 2) % n_banks`` sits in tile ``b // banks_per_tile``
+    (``banks_per_tile = banking_factor * cores_per_tile``); a tile sits in
+    group ``tile // tiles_per_group``;
+  * the hop latency ``h(c, b)`` is ``latency[0]`` inside the core's tile,
+    ``latency[1]`` inside its group and ``latency[2]`` beyond (MemPool: 1,
+    3 and 5 cycles);
+  * arbitration is unchanged: one grant per bank per cycle, round robin
+    from the last winner, in the cycle the request issues;
+  * a granted load, poll or TAS keeps its PE in ``STALL_MEM`` for ``h - 1``
+    more cycles (counted as wait and stall, as a lost arbitration is) before
+    the PE goes on exactly as after a flat grant: the poll's hit or miss
+    cycles, or ``TAS_CYCLES - 1``; the word is read and written in the
+    grant cycle;
+  * a store is posted and pays no response latency;
+  * contention inside the tile, group and global crossbars is not modelled.
+
+With every latency 1 the cluster is the flat cluster bit for bit.  Only
+``mode="lockstep"`` and the device executor (:mod:`repro.core.scu.trace`)
+run a hierarchical cluster: the fast-forward tiers and the fleets model
+the flat interconnect, and refuse it.
+
 Two execution modes produce bit-exact identical :class:`ClusterStats`:
 
 ``mode="lockstep"``
@@ -105,6 +130,7 @@ __all__ = [
     "ClusterStats",
     "Cluster",
     "FleetConfig",
+    "Hierarchy",
     "Program",
     "simulate_fleet",
     "SlotFleet",
@@ -195,6 +221,30 @@ class Scu:
 
 
 Program = Callable[["Cluster", int], Generator]
+
+
+@dataclasses.dataclass(frozen=True)
+class Hierarchy:
+    """Tiles and groups of a hierarchical interconnect, and its hop
+    latencies (see the module docstring); ``None`` in its place is the flat
+    PULP cluster."""
+
+    cores_per_tile: int
+    tiles_per_group: int
+    groups: int
+    latency: Tuple[int, int, int]  # tile, group, remote (MemPool: 1, 3, 5)
+
+    @property
+    def n_cores(self) -> int:
+        return self.cores_per_tile * self.tiles_per_group * self.groups
+
+    def hop_class(self, core, bank, n_banks: int):
+        """0 where ``bank`` lies in ``core``'s tile, 1 in its group, 2
+        beyond; elementwise on integer arrays."""
+        tile = np.asarray(core) // self.cores_per_tile
+        bank_tile = np.asarray(bank) // (n_banks * self.cores_per_tile // self.n_cores)
+        same_group = tile // self.tiles_per_group == bank_tile // self.tiles_per_group
+        return np.where(tile == bank_tile, 0, np.where(same_group, 1, 2))
 
 
 class CoreState(enum.Enum):
@@ -329,6 +379,7 @@ class _Core:
         "sleep_entry",
         "elw_issued",
         "finished_at",
+        "hop",
     ) + _COUNTERS
 
     def __init__(self, cid: int, gen: Generator):
@@ -343,6 +394,7 @@ class _Core:
         self.sleep_entry = 0  # busy-release window before clock gating
         self.elw_issued = False  # extension trigger-once guard (Sec. 5)
         self.finished_at: Optional[int] = None
+        self.hop = 0  # response cycles left after a hierarchical grant
         for name in _COUNTERS:
             setattr(self, name, 0)
 
@@ -566,6 +618,10 @@ class Cluster:
         bit-exact between the two modes.  Plans are single-use; pass a
         fresh (or :meth:`~repro.core.scu.faults.FaultPlan.clone`\\ d) plan
         per cluster.
+    hierarchy:
+        A :class:`Hierarchy` of ``n_cores`` cores for a MemPool-style
+        interconnect with hop latencies, or ``None`` (default) for the flat
+        single-cycle one.  Runs in ``mode="lockstep"`` only.
     """
 
     MODES = ("fastforward", "lockstep")
@@ -608,11 +664,19 @@ class Cluster:
         banking_factor: int = 2,
         mode: str = "fastforward",
         faults: Optional[FaultPlan] = None,
+        hierarchy: Optional[Hierarchy] = None,
     ):
         if mode not in self.MODES:
             raise ValueError(f"mode must be one of {self.MODES}, got {mode!r}")
+        if hierarchy is not None and (
+            hierarchy.n_cores != n_cores or min(hierarchy.latency) < 1
+        ):
+            raise ValueError(
+                f"{hierarchy} does not describe {n_cores} cores with latencies >= 1"
+            )
         self.n_cores = n_cores
         self.n_banks = banking_factor * n_cores
+        self.hierarchy = hierarchy
         self.scu = scu
         self.mode = mode
         self.faults = faults
@@ -676,6 +740,12 @@ class Cluster:
 
     def run(self, max_cycles: int = 10_000_000) -> ClusterStats:
         self.max_cycles = max_cycles
+        if self.mode == "fastforward" and self.hierarchy is not None:
+            raise ValueError(
+                "the fast-forward tiers model a flat interconnect: run a "
+                "hierarchical cluster with mode='lockstep' or on the device "
+                "executor (repro.core.scu.trace.run_traces_jax)"
+            )
         try:
             if self.mode == "fastforward":
                 self._run_fast(max_cycles)
@@ -1599,6 +1669,11 @@ class Cluster:
             core.sleep_entry -= 1
             if core.sleep_entry <= 0:
                 core.state = CoreState.SLEEP
+        elif state is CoreState.STALL_MEM and core.hop > 0:
+            # a hierarchical grant's response on its way back
+            core.hop -= 1
+            if core.hop == 0:
+                core.state = CoreState.ACTIVE
 
     def _bank_of(self, addr: int) -> int:
         return (addr >> 2) % self.n_banks
@@ -1606,7 +1681,7 @@ class Cluster:
     def _arbitrate_tcdm(self) -> None:
         by_bank: Dict[int, List[_Core]] = {}
         for core in self.cores:
-            if core.state is CoreState.STALL_MEM:
+            if core.state is CoreState.STALL_MEM and core.hop == 0:
                 by_bank.setdefault(self._bank_of(core.pending.addr), []).append(core)
         if by_bank and self.faults is not None:
             blk = self.faults.blacked_banks(self.cycle)
@@ -1623,7 +1698,15 @@ class Cluster:
             winner = reqs[0]
             self._rr[bank] = (winner.cid + 1) % n
             self.stats.bank_conflicts += len(reqs) - 1
+            posted = winner.pending.kind == "sw"
             self._grant_mem(winner)
+            hier = self.hierarchy
+            if hier is not None and not posted:
+                # the response crosses the interconnect: h - 1 more cycles
+                h = hier.latency[int(hier.hop_class(winner.cid, bank, self.n_banks))]
+                if h > 1:
+                    winner.state = CoreState.STALL_MEM
+                    winner.hop = h - 1
 
     def _grant_mem(self, winner: _Core) -> None:
         """Execute a granted TCDM transaction (shared by both arbiters)."""
@@ -1827,6 +1910,11 @@ def _check_fleet_config(cfg: FleetConfig, label: str, needs: str) -> None:
         )
     if cl.n_cores < 1:
         raise ValueError(f"{label}: cluster has no cores")
+    if cl.hierarchy is not None:
+        raise ValueError(
+            f"{label}: the fleet's vectorized tiers model a flat "
+            "interconnect, not a hierarchical cluster"
+        )
 
 
 class _FleetEngine:

@@ -10,7 +10,7 @@ uses ``nop`` runs), tunable to sweep Fig. 5.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from .engine import Cluster, ClusterStats, Compute, FleetConfig, Mem, simulate_fleet
 from .primitives import DEFAULT_COSTS
@@ -52,8 +52,10 @@ class MicrobenchResult:
     stats: ClusterStats
 
 
-def _make_cluster(n_cores: int, mode: str = "fastforward") -> Cluster:
-    return Cluster(n_cores=n_cores, scu=SCU(n_cores=n_cores), mode=mode)
+def _make_cluster(
+    n_cores: int, mode: str = "fastforward", interconnect: Optional[Dict[str, Any]] = None,
+) -> Cluster:
+    return Cluster(n_cores=n_cores, scu=SCU(n_cores=n_cores), mode=mode, **(interconnect or {}))
 
 
 def _lower_loop_programs(
@@ -207,17 +209,21 @@ def make_fleet(benches: Sequence[FleetBench]) -> List[MicrobenchResult]:
 def prep_barrier_bench(
     variant: str, n_cores: int, sfr: int = 0, iters: int = 256, cost_model=None,
     mode: str = "fastforward", compiled: bool = False,
+    interconnect: Optional[Dict[str, Any]] = None,
 ) -> FleetBench:
     """Prepare (without running) a barrier microbenchmark config.
 
     ``compiled=True`` lowers every core's program to a static trace
     (:mod:`repro.core.scu.trace`) -- bit-exact stats, and fully-traced runs
     collapse repeated whole-cluster periods instead of simulating them.
+    ``interconnect`` holds the TCDM's :class:`Cluster` arguments,
+    ``banking_factor`` and ``hierarchy``; ``None`` is the flat PULP
+    cluster's (banking factor 2).
     """
     from repro.sync import get_policy  # deferred: repro.sync imports this pkg
 
     policy = get_policy(variant)
-    cl = _make_cluster(n_cores, mode)
+    cl = _make_cluster(n_cores, mode, interconnect)
     state = policy.make_sim_state(n_cores)
     cm = cost_model or DEFAULT_COSTS
 
@@ -274,12 +280,14 @@ def run_barrier_bench(
 def prep_mutex_bench(
     variant: str, n_cores: int, t_crit: int = 0, sfr: int = 0, iters: int = 256,
     cost_model=None, mode: str = "fastforward", compiled: bool = False,
+    interconnect: Optional[Dict[str, Any]] = None,
 ) -> FleetBench:
-    """Prepare (without running) a mutex microbenchmark config."""
+    """Prepare (without running) a mutex microbenchmark config;
+    ``interconnect`` as :func:`prep_barrier_bench` takes it."""
     from repro.sync import get_policy  # deferred: repro.sync imports this pkg
 
     policy = get_policy(variant)
-    cl = _make_cluster(n_cores, mode)
+    cl = _make_cluster(n_cores, mode, interconnect)
     state = policy.make_sim_state(n_cores)
     cm = cost_model or DEFAULT_COSTS
 

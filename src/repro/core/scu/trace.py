@@ -56,7 +56,7 @@ import numpy as np
 
 from repro import obs
 
-from .engine import _COUNTERS, Cluster, Compute, Mem, Poll, Scu
+from .engine import _COUNTERS, Cluster, Compute, Hierarchy, Mem, Poll, Scu
 from .scu_unit import EV
 
 __all__ = [
@@ -856,10 +856,13 @@ def _encode_scu(op: Scu, n: int) -> Tuple[int, int]:
     return code, inst
 
 
-def _pack_tables(programs: Sequence[TraceProgram]):
+def _pack_tables(programs: Sequence[TraceProgram], hierarchy: Optional[Hierarchy] = None,
+                 n_banks: int = 0):
     """Flatten trace tables into padded per-lane numpy arrays.  Also returns
     the SCU's shape, ``(barriers, mutexes)`` the tables address, or ``None``
-    when no table has an SCU row."""
+    when no table has an SCU row.  Under a ``hierarchy`` of ``n_banks``
+    banks the table gains a tenth column: each memory row's hop class for
+    its lane (:meth:`Hierarchy.hop_class`; 0 on every other row)."""
     for p in programs:
         if not p.is_traced:
             raise ValueError("array executor needs pure traced programs")
@@ -886,17 +889,26 @@ def _pack_tables(programs: Sequence[TraceProgram]):
                 elif code in (_S_MTX_LOCK, _S_MTX_UNLOCK, _S_RD_MTX):
                     n_mtx = max(n_mtx, inst + 1)
     scu = (max(n_bar, 1), max(n_mtx, 1)) if has_scu else None
-    return tab, np.array(addrs, dtype=np.int64), scu
+    addrs = np.array(addrs, dtype=np.int64)
+    if hierarchy is not None:
+        mem = (tab[:, :, 0] == T_MEM) | (tab[:, :, 0] == T_POLL)
+        bank = (addrs[np.where(mem, tab[:, :, 3], 0)] >> 2) % n_banks if len(addrs) else 0
+        cls = hierarchy.hop_class(np.arange(n)[:, None], bank, n_banks)
+        tab = np.concatenate([tab, np.where(mem, cls, 0)[:, :, None]], axis=2)
+    return tab, addrs, scu
 
 
 class _Tables(NamedTuple):
     """The executor's read-only inputs, passed to every phase: the packed
     table once, column-major as ``(10, lanes, rows)`` (the nine packed
-    columns, then the TCDM bank of a memory row's address), the row and
-    lane indices, the cycle cap and the static sizes.
+    columns, then the TCDM bank of a memory row's address; under a
+    hierarchy an eleventh, the row's hop class), the row and lane indices,
+    the cycle cap and the static sizes.
     ``xp`` is the array namespace they live in.  ``scu`` is ``(barriers,
     mutexes)``, or ``None`` for tables without SCU rows, which then carry
-    no SCU state and run no SCU phase."""
+    no SCU state and run no SCU phase.  ``hop`` is the hierarchy's
+    latencies (tile, group, remote), or ``None`` for the flat cluster,
+    whose tables carry no response state and run no ``scu.hop`` step."""
 
     xp: Any
     tab: Any
@@ -907,6 +919,7 @@ class _Tables(NamedTuple):
     n_banks: int
     tas_cycles: int
     scu: Optional[Tuple[int, int]]
+    hop: Optional[Tuple[int, int, int]]
 
 
 def _scoped(name: str):
@@ -949,7 +962,8 @@ def _pick(t, arr, hot):
 
 def _row(t, idx):
     """Each lane's table row ``idx``, as ``(10, lanes)``: row ``c`` holds
-    column ``c`` (op kind, repeat count, ``a0``..``a6``, bank).  One masked
+    column ``c`` (op kind, repeat count, ``a0``..``a6``, bank, and under a
+    hierarchy the hop class).  One masked
     sum over the rows fetches every column; ``idx`` must lie in the table."""
     hot = idx[:, None] == t.rows[None, :]
     return t.xp.where(hot[None], t.tab, 0).sum(axis=2, dtype=t.tab.dtype)
@@ -1054,8 +1068,10 @@ def _grant(t, s):
     xp, lanes, n, tas_cycles = t.xp, t.lanes, t.n, t.tas_cycles
     st, pend, rr = s["st"], s["pend"], s["rr"]
     req = st == _X_STALL
-    r_kind, _, m_kind, aidx, until, hit_c, miss_c, hit_i, miss_i, bank = _row(
-        t, xp.where(req, pend, 0))  # T_MEM / T_POLL rows
+    if t.hop:
+        req = req & (s["resp"] == 0)  # not a lane awaiting its response
+    row = _row(t, xp.where(req, pend, 0))  # T_MEM / T_POLL rows
+    r_kind, _, m_kind, aidx, until, hit_c, miss_c, hit_i, miss_i, bank = row[:10]
     # each bank grants its request of least key, the lanes counted from
     # the bank's round-robin pointer; keys differ, so one lane per bank
     big = n + 1
@@ -1100,6 +1116,38 @@ def _grant(t, s):
     s = dict(s)
     s.update(st=new_st, pend=pend, busy=busy, R=R, tcdm=tcdm, rr=rr,
              cnt=cnt, conflicts=conflicts)
+    if t.hop:
+        s = _hop_grant(t, s, win, is_sw, row[10])
+    return s
+
+
+@_scoped("scu.hop")
+def _hop_grant(t, s, win, posted, cls):
+    """A hierarchical grant: each granted load, poll or TAS waits for its
+    response ``h - 1`` more cycles in ``STALL`` (``resp`` counts them down,
+    :func:`_await_response`), ``h`` the hop latency of its class; a posted
+    store goes on at once.  Grants outside the lane's tile count in
+    ``remote``."""
+    xp, (tile, group, remote) = t.xp, t.hop
+    lat = xp.where(cls == 0, np.int32(tile), xp.where(cls == 1, np.int32(group), np.int32(remote)))
+    held = win & ~posted & (lat > 1)
+    s = dict(s)
+    s["st"] = xp.where(held, _X_STALL, s["st"])
+    s["resp"] = xp.where(held, lat - 1, s["resp"])
+    s["remote"] = s["remote"] + (win & (cls != 0))
+    return s
+
+
+@_scoped("scu.hop")
+def _await_response(t, s):
+    """Phase 1 of a lane awaiting its response: count down; it turns ACTIVE
+    as the response arrives and goes on from there next cycle."""
+    xp = t.xp
+    waiting = s["resp"] > 0
+    resp = xp.where(waiting, s["resp"] - 1, s["resp"])
+    s = dict(s)
+    s["resp"] = resp
+    s["st"] = xp.where(waiting & (resp == 0), _X_ACTIVE, s["st"])
     return s
 
 
@@ -1329,6 +1377,9 @@ def _cycle_step(t, s):
         )
         s = _issue_data(t, s, _row(t, s["pc"]))
     s.pop("fetch", None)
+    if t.hop:
+        # the lanes awaiting a response: no other phase-1 step touches them
+        s = _await_response(t, s)
     # Phase 2: arbitration + grants.  Phases 3 and 4: the SCU's links and
     # elw grants.  Then the jump over the quiet cycles that follow, and
     # phase 5: accounting, for this cycle and those.
@@ -1346,7 +1397,9 @@ def _quiet_jump(t, s):
     ``busy`` and ``wake`` in one update (its ``fast_forward``); phase 5
     accounts them as ``s["jump"]``.  A lane stays quiet while it is ACTIVE
     and counts down ``busy``, WAKE and counts down ``wake`` past 1, or
-    asleep on an ``elw`` that nothing grants; a DONE lane bounds nothing.
+    asleep on an ``elw`` that nothing grants, or awaits a response (under
+    a hierarchy) and counts down ``resp`` past 1; a DONE lane bounds
+    nothing.
     Any other lane, an extension about to fire, or no bounded lane at all
     gives 0, and the jump never passes the cycle cap.  A sleeper waits on
     the event lines :func:`_scu_wake` left in ``s["elw_wait"]``.  The same
@@ -1363,6 +1416,9 @@ def _quiet_jump(t, s):
         bound = xp.where(waking, s["wake"] - 1, bound)
         wait = s.pop("elw_wait")
         bound = xp.where((st == _X_SLEEP) & ((s["ev_buf"] & wait) == 0), _I32_MAX - 1, bound)
+    if t.hop:
+        waiting = s["resp"] > 0
+        bound = xp.where(waiting, s["resp"] - 1, bound)
     least = bound.min()
     k = xp.where(least >= _I32_MAX - 1, 0, least)
     if t.scu:
@@ -1374,23 +1430,31 @@ def _quiet_jump(t, s):
     s["busy"] = xp.where(act, s["busy"] - k, s["busy"])
     if t.scu:
         s["wake"] = xp.where(waking, s["wake"] - k, s["wake"])
+    if t.hop:
+        s["resp"] = xp.where(waiting, s["resp"] - k, s["resp"])
     return s
 
 
 def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
-             scu: Optional[Tuple[int, int]]):
+             scu: Optional[Tuple[int, int]], hop: Optional[Tuple[int, int, int]] = None):
     """Run the packed int32 table ``(lanes, rows, 9)`` from a cleared
     cluster until every lane halts or ``max_cycles`` cycles pass; the final
     state.  ``addr_bank`` is each TCDM address's bank, looked up once here
     for every memory row, so the loop reads it with the row; ``scu`` is
-    :func:`_pack_tables`'s SCU shape.  Under jax this is the body of
-    :func:`_jitted_execute`, so the tables are its inputs."""
+    :func:`_pack_tables`'s SCU shape.  Under a hierarchy ``hop`` is its
+    latencies and the table has a tenth column, the hop classes.  Under jax
+    this is the body of :func:`_jitted_execute`, so the tables are its
+    inputs."""
     n, length, _ = tab.shape
     mem = (tab[:, :, 0] == T_MEM) | (tab[:, :, 0] == T_POLL)
     row_bank = addr_bank[xp.where(mem, tab[:, :, 3], 0)]
+    if hop is None:
+        cols = [xp.transpose(tab, (2, 0, 1)), row_bank[None]]
+    else:
+        cols = [xp.transpose(tab[:, :, :9], (2, 0, 1)), row_bank[None], tab[None, :, :, 9]]
     t = _Tables(
-        xp, xp.concatenate([xp.transpose(tab, (2, 0, 1)), row_bank[None]]),
-        xp.arange(length), xp.arange(n), max_cycles, n, n_banks, tas_cycles, scu,
+        xp, xp.concatenate(cols),
+        xp.arange(length), xp.arange(n), max_cycles, n, n_banks, tas_cycles, scu, hop,
     )
     state = {
         "pc": xp.zeros(n, dtype=xp.int32),
@@ -1426,6 +1490,9 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
             msg=xp.zeros(n_mtx, dtype=xp.int32),
             stamp=xp.full((n_mtx, n), -1, dtype=xp.int32),
         )
+    if hop:
+        # each lane's response countdown and its grants outside its tile
+        state.update(resp=xp.zeros(n, dtype=xp.int32), remote=xp.zeros(n, dtype=xp.int32))
     if xp is np:
         while state["live"] and state["cycle"] < max_cycles:
             state["fetch"] = np.zeros(n, dtype=bool)
@@ -1452,13 +1519,13 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
 def _jitted_execute():
     """:func:`_execute` on ``jax.numpy`` as one ``jax.jit`` program, built
     on first use (jax is optional).  jax keeps one executable per table
-    shape, ``n_banks``, ``tas_cycles`` and SCU shape; ``max_cycles`` is an
-    input."""
+    shape, ``n_banks``, ``tas_cycles``, SCU shape and hop latencies;
+    ``max_cycles`` is an input."""
     import jax
     import jax.numpy as jnp
 
     return jax.jit(functools.partial(_execute, jnp),
-                   static_argnames=("n_banks", "tas_cycles", "scu"))
+                   static_argnames=("n_banks", "tas_cycles", "scu", "hop"))
 
 
 def run_traces_xp(
@@ -1468,6 +1535,7 @@ def run_traces_xp(
     tas_cycles: int = 3,
     max_cycles: int = 10_000_000,
     xp=np,
+    hierarchy: Optional[Hierarchy] = None,
 ):
     """Execute traces as one batched array computation.
 
@@ -1486,6 +1554,14 @@ def run_traces_xp(
     and the final tcdm contents; parity vs the generator engine is enforced
     by ``tests/test_trace.py``.
 
+    ``hierarchy`` (a :class:`~repro.core.scu.engine.Hierarchy` of one core
+    per program) runs the programs on a MemPool-style interconnect, with
+    the lockstep engine's semantics: a granted load, poll or TAS answers
+    after its hop latency, a store is posted.  Each memory row's hop class
+    is packed with the table; the grant starts each held lane's response
+    countdown, and phase 1 counts it down (named scope ``scu.hop``).  A
+    flat table (``None``) runs the program it ran before hierarchies.
+
     Consumes the programs (single-use), mirroring the cursor path.
 
     Each call records host spans (:mod:`repro.obs`): ``scu.run`` around it
@@ -1499,9 +1575,11 @@ def run_traces_xp(
     and account phases carry the named scopes ``scu.issue``,
     ``scu.decode``, ``scu.grant`` and ``scu.account``, the SCU phase of a
     table with SCU rows ``scu.sync``, and the jump ``scu.jump``.  The
-    readback counts the job's SCU transactions as ``scu.sync_ops`` and its
-    loop iterations as ``scu.loop_iterations``, fetched with the cycle
-    count and the finish flag in one transfer.
+    readback counts the job's SCU transactions as ``scu.sync_ops``, its
+    loop iterations as ``scu.loop_iterations`` and its granted TCDM
+    accesses to banks outside the lane's tile as ``scu.remote_accesses``
+    (0 on a flat cluster), the last two fetched with the cycle count and
+    the finish flag in one transfer.
 
     One loop iteration is one simulated cycle and a jump (:func:`_quiet_jump`)
     over the quiet cycles after it: while no lane can act -- every live
@@ -1528,7 +1606,10 @@ def run_traces_xp(
                 if p._consumed:
                     raise RuntimeError("TraceProgram already consumed (single-use)")
                 p._consumed = True
-            tab_np, addrs_np, scu = _pack_tables(programs)
+            if hierarchy is not None and hierarchy.n_cores != len(programs):
+                raise ValueError(f"{hierarchy} does not describe {len(programs)} lanes")
+            tab_np, addrs_np, scu = _pack_tables(programs, hierarchy, n_banks)
+            hop = None if hierarchy is None else tuple(hierarchy.latency)
             is_np = xp is np
             # All state is int32 under both namespaces (jax without x64 has no
             # int64).  A counter grows by at most 2 + the largest poll instruction
@@ -1551,14 +1632,17 @@ def run_traces_xp(
         with obs.span("scu.loop"):
             run = functools.partial(_execute, np) if is_np else _jitted_execute()
             state = run(tab, addr_bank, np.int32(max_cycles), n_banks=n_banks,
-                        tas_cycles=tas_cycles, scu=scu)
+                        tas_cycles=tas_cycles, scu=scu, hop=hop)
         with obs.span("scu.wait"):
             if not is_np:
                 jax.block_until_ready(state)
         with obs.span("scu.readback"):
             scalars = (state["cycle"], state["iters"], state["live"])
-            cycles, iters, live = map(int, scalars if is_np else jax.device_get(scalars))
+            if hop:
+                scalars += (state["remote"].sum(),)
+            cycles, iters, live, *remote = map(int, scalars if is_np else jax.device_get(scalars))
             obs.count("scu.loop_iterations", iters)
+            obs.count("scu.remote_accesses", sum(remote))
             if live:
                 raise RuntimeError(f"traced run did not finish within {max_cycles} cycles")
 
@@ -1582,6 +1666,7 @@ def run_traces_jax(
     n_banks: int,
     tas_cycles: int = 3,
     max_cycles: int = 10_000_000,
+    hierarchy: Optional[Hierarchy] = None,
 ):
     """:func:`run_traces_xp` on ``jax.numpy``: the cycle loop runs as one
     ``jax.jit`` program (an XLA while loop) kept in memory per table shape.
@@ -1597,5 +1682,5 @@ def run_traces_jax(
 
     return run_traces_xp(
         programs, n_banks=n_banks, tas_cycles=tas_cycles,
-        max_cycles=max_cycles, xp=jnp,
+        max_cycles=max_cycles, xp=jnp, hierarchy=hierarchy,
     )

@@ -14,6 +14,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core.scu import (
+    Hierarchy,
     NotCheckpointable,
     capture_cluster,
     restore_cluster,
@@ -175,6 +176,21 @@ def test_generator_programs_are_not_checkpointable():
         fl.suspend(slot)
     assert fl.members[slot] is not None and not fl.members[slot].done
     _run_fleet(fl)  # still completes
+
+
+def test_hierarchical_clusters_are_not_checkpointable():
+    """A hierarchical cluster runs in lockstep only, and its cores' response
+    countdowns are not captured: a snapshot refuses it rather than restore
+    it flat."""
+    h = Hierarchy(cores_per_tile=4, tiles_per_group=1, groups=2, latency=(1, 3, 5))
+    fb = prep_barrier_bench("tree", 8, sfr=0, iters=2, compiled=True, mode="lockstep",
+                            interconnect={"banking_factor": 2, "hierarchy": h})
+    cl = fb.config.cluster
+    cl.load(fb.config.programs)
+    for _ in range(5):
+        cl.step()
+    with pytest.raises(NotCheckpointable, match="hierarchical"):
+        capture_cluster(cl)
 
 
 def test_snapshot_restore_slot_errors():

@@ -94,3 +94,23 @@ def test_scu_barrier_compiles_in_shard_map_over_four_chips(topo):
     arrive = _sds((len(devices),), jnp.float32, NamedSharding(mesh, P("x")))
     compiled = fn.lower(arrive).compile()
     _assert_kernel(compiled)
+
+
+def test_executor_compiles_at_mempool_width(one_chip):
+    """The device executor's loop at MemPool's width (the ``tree`` barrier
+    on 256 lanes and 1,024 banks under the 1/3/5-cycle hierarchy, the
+    ``sim.mempool-256pe`` cell's job) compiles for the chip, its tables
+    under a megabyte."""
+    from repro.core.scu import Hierarchy
+    from repro.core.scu.programs import prep_barrier_bench
+    from repro.core.scu.trace import _jitted_execute, _pack_tables
+
+    h = Hierarchy(cores_per_tile=4, tiles_per_group=16, groups=4, latency=(1, 3, 5))
+    fb = prep_barrier_bench("tree", 256, iters=16, compiled=True,
+                            interconnect={"banking_factor": 4, "hierarchy": h})
+    tab, addrs, scu = _pack_tables(fb.config.programs, h, 1024)
+    compiled = _jitted_execute().lower(
+        _sds(tab.shape, jnp.int32, one_chip), _sds(addrs.shape, jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), n_banks=1024, tas_cycles=3, scu=scu, hop=h.latency,
+    ).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes < 1 << 20

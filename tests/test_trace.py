@@ -14,6 +14,7 @@ of wall clock, and the trace semantics they would exercise are identical to
 the 8-core runs that do cover them.
 """
 
+import dataclasses
 import functools
 import time
 
@@ -22,9 +23,10 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core.scu import SCU, Cluster, Compute, Mem
-from repro.core.scu.engine import _COUNTERS
+from repro.core.scu import SCU, Cluster, Compute, Hierarchy, Mem
+from repro.core.scu.engine import _COUNTERS, SlotFleet
 from repro.core.scu.programs import (
+    make_fleet,
     prep_barrier_bench,
     prep_chain_bench,
     prep_mutex_bench,
@@ -423,20 +425,26 @@ def test_table_without_scu_rows_carries_no_scu_state():
 
 
 @pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
-@pytest.mark.parametrize("policy", ["sw", "scu"])
+@pytest.mark.parametrize("policy", ["sw", "scu", "tree-hierarchy"])
 def test_loop_body_has_no_gather_or_scatter(policy):
     """Every read and write of the loop body is a dense one-hot select: the
     outer cycle loop and the decode loop nested in it hold no gather and no
-    scatter, each of which is a device kernel of its own."""
+    scatter, each of which is a device kernel of its own.  The same holds
+    for a hierarchical table's response countdown."""
     import jax
     import jax.numpy as jnp
 
     from repro.core.scu.trace import _execute, _pack_tables
 
-    fb = prep_barrier_bench(policy, 8, sfr=0, iters=2, compiled=True)
-    tab, addrs, scu = _pack_tables(fb.config.programs)
+    if policy == "tree-hierarchy":
+        fb = _HIER_JOBS["tree"](_HIER)
+        tab, addrs, scu = _pack_tables(fb.config.programs, _HIER, 64)
+        kw = dict(n_banks=64, tas_cycles=3, scu=scu, hop=_HIER.latency)
+    else:
+        fb = prep_barrier_bench(policy, 8, sfr=0, iters=2, compiled=True)
+        tab, addrs, scu = _pack_tables(fb.config.programs)
+        kw = dict(n_banks=16, tas_cycles=3, scu=scu)
     args = (tab.astype(np.int32), np.zeros(max(len(addrs), 1), np.int32), np.int32(100))
-    kw = dict(n_banks=16, tas_cycles=3, scu=scu)
     jaxpr = jax.make_jaxpr(functools.partial(_execute, jnp, **kw))(*args)
     (outer,) = [e for e in jaxpr.eqns if e.primitive.name == "while"]
     seen = []
@@ -526,9 +534,11 @@ def test_executor_counts_its_scu_transactions(xp_name, policy):
     fb = prep_barrier_bench(policy, 4, sfr=0, iters=3, compiled=True)
     t0 = time.perf_counter()
     res = run(fb.config.programs, n_banks=fb.config.cluster.n_banks)
-    counts = [e.n for e in obs.events(t0, time.perf_counter()) if e.name == "scu.sync_ops"]
+    ev = obs.events(t0, time.perf_counter())
+    counts = [e.n for e in ev if e.name == "scu.sync_ops"]
     assert counts == [int(res["counters"]["scu_accesses"].sum())]
     assert (counts[0] > 0) == (policy == "scu")
+    assert [e.n for e in ev if e.name == "scu.remote_accesses"] == [0]  # a flat cluster
 
 
 _PHASES = ["scu.pack", "scu.stage", "scu.loop", "scu.wait", "scu.readback"]
@@ -770,3 +780,141 @@ def test_compiled_fleet_row_is_jumping():
     cl = fb.config.cluster
     assert cl.trace_jumps >= 1
     assert 0 < cl.trace_jump_cycles < got.stats.cycles
+
+
+# A MemPool-style cluster at test size: 16 cores in 4 tiles of 4, 2 tiles a
+# group, 2 groups and 64 banks (banking factor 4), so that every hop class
+# (tile, group, remote) is present.  Each job takes the hierarchy and the
+# engine mode.
+_HIER = Hierarchy(cores_per_tile=4, tiles_per_group=2, groups=2, latency=(1, 3, 5))
+_HIER_JOBS = {
+    "sw": lambda h, mode="lockstep": prep_barrier_bench(
+        "sw", 16, sfr=0, iters=3, compiled=True, mode=mode,
+        interconnect={"banking_factor": 4, "hierarchy": h}),
+    "tree": lambda h, mode="lockstep": prep_barrier_bench(
+        "tree", 16, sfr=0, iters=4, compiled=True, mode=mode,
+        interconnect={"banking_factor": 4, "hierarchy": h}),
+    "tree4": lambda h, mode="lockstep": prep_barrier_bench(
+        "tree4", 16, sfr=20, iters=4, compiled=True, mode=mode,
+        interconnect={"banking_factor": 4, "hierarchy": h}),
+    "mutex": lambda h, mode="lockstep": prep_mutex_bench(
+        "sw", 16, t_crit=3, iters=2, compiled=True, mode=mode,
+        interconnect={"banking_factor": 4, "hierarchy": h}),
+}
+
+
+def _engine_run(fb):
+    """The lockstep engine's run of a prepared job: its stats, its cluster
+    and the count of its grants to a bank outside the granted core's tile."""
+    cl = fb.config.cluster
+    remote = [0]
+    grant = cl._grant_mem
+
+    def counted(core):
+        bank = cl._bank_of(core.pending.addr)
+        remote[0] += int(cl.hierarchy.hop_class(core.cid, bank, cl.n_banks)) != 0
+        grant(core)
+
+    if cl.hierarchy is not None:
+        cl._grant_mem = counted
+    cl.load(fb.config.programs)
+    return cl.run(), cl, remote[0]
+
+
+def _hier_run(xp_name, fb):
+    """One executor call on ``fb``'s cluster: its result and its
+    ``scu.remote_accesses`` counts."""
+    if xp_name == "jax" and not HAS_JAX:
+        pytest.skip("jax unavailable")
+    from repro import obs
+    from repro.core.scu.trace import run_traces_jax
+
+    run = run_traces_xp if xp_name == "numpy" else run_traces_jax
+    cl = fb.config.cluster
+    t0 = time.perf_counter()
+    res = run(fb.config.programs, n_banks=cl.n_banks, hierarchy=cl.hierarchy)
+    ev = obs.events(t0, time.perf_counter())
+    return res, [e.n for e in ev if e.name == "scu.remote_accesses"]
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+@pytest.mark.parametrize("job", tuple(_HIER_JOBS))
+def test_executors_match_engine_on_a_hierarchy(job, xp_name):
+    """MemPool's hop latencies (1, 3 and 5 cycles) against the lockstep
+    engine, bit for bit: cycles, the nine counters, conflicts, retire
+    cycles and the final TCDM words; and ``scu.remote_accesses`` is the
+    engine's count of grants outside the core's tile.  The hops change the
+    run: the flat cluster's differs."""
+    ref, cl, remote = _engine_run(_HIER_JOBS[job](_HIER))
+    res, counted = _hier_run(xp_name, _HIER_JOBS[job](_HIER))
+    _assert_matches_engine(res, cl, ref)
+    assert counted == [remote] and remote > 0
+    flat, _, _ = _engine_run(_HIER_JOBS[job](None))
+    assert flat != ref
+
+
+@pytest.mark.parametrize("xp_name", ["engine", "numpy", "jax"])
+@pytest.mark.parametrize("job", tuple(_HIER_JOBS))
+def test_one_cycle_hops_are_the_flat_cluster(job, xp_name):
+    """A hierarchy whose every latency is 1 runs as the flat cluster, bit
+    for bit; only the remote count tells them apart (0 when flat)."""
+    ones = dataclasses.replace(_HIER, latency=(1, 1, 1))
+    if xp_name == "engine":
+        (got, cl_got, remote), (ref, cl_ref, _) = (
+            _engine_run(_HIER_JOBS[job](h)) for h in (ones, None))
+        assert got == ref and cl_got.tcdm == cl_ref.tcdm and remote > 0
+        return
+    (got, remote), (ref, flat) = (_hier_run(xp_name, _HIER_JOBS[job](h)) for h in (ones, None))
+    assert got["cycles"] == ref["cycles"]
+    assert got["bank_conflicts"] == ref["bank_conflicts"]
+    for name in _COUNTERS:
+        assert got["counters"][name].tolist() == ref["counters"][name].tolist(), name
+    assert got["finished_at"].tolist() == ref["finished_at"].tolist()
+    assert got["tcdm"] == ref["tcdm"]
+    assert flat == [0] and remote[0] > 0
+
+
+@pytest.mark.parametrize("tier", ["fastforward", "fleet", "slot_fleet"])
+def test_fast_forward_tiers_refuse_a_hierarchy(tier):
+    """The fast-forward tiers and the fleets model the flat interconnect:
+    they refuse a hierarchical cluster by name rather than run it flat."""
+    fb = _HIER_JOBS["tree"](_HIER, mode="fastforward")
+    with pytest.raises(ValueError, match="flat interconnect"):
+        if tier == "fastforward":
+            fb.run_sequential()
+        elif tier == "fleet":
+            make_fleet([fb])
+        else:
+            SlotFleet(n_slots=1, slot_cores=16, banking_factor=4).admit(fb.config)
+
+
+def test_a_hierarchy_must_describe_the_cluster():
+    with pytest.raises(ValueError, match="8 cores"):
+        Cluster(n_cores=8, hierarchy=_HIER)
+    with pytest.raises(ValueError, match="latencies >= 1"):
+        Cluster(n_cores=16, hierarchy=dataclasses.replace(_HIER, latency=(0, 3, 5)))
+    with pytest.raises(ValueError, match="8 lanes"):
+        run_traces_xp(_tcdm_traces(8), n_banks=32, hierarchy=_HIER)
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
+@pytest.mark.parametrize("hierarchy", [None, _HIER], ids=["flat", "hierarchy"])
+def test_only_a_hierarchical_table_carries_response_state(hierarchy):
+    """A flat table's loop carries the 16 arrays of the TCDM state and has
+    no ``scu.hop`` scope, as before hierarchies; a hierarchical one carries
+    each lane's response countdown and remote count besides, under
+    ``scu.hop``."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.scu.trace import _execute, _jitted_execute, _pack_tables
+
+    fb = _HIER_JOBS["tree"](hierarchy)
+    tab, addrs, scu = _pack_tables(fb.config.programs, hierarchy, 64)
+    assert tab.shape[2] == (9 if hierarchy is None else 10)
+    args = (tab.astype(np.int32), np.zeros(len(addrs), np.int32), np.int32(100))
+    kw = dict(n_banks=64, tas_cycles=3, scu=scu, hop=hierarchy and hierarchy.latency)
+    jaxpr = jax.make_jaxpr(functools.partial(_execute, jnp, **kw))(*args)
+    (w,) = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    text = _jitted_execute().lower(*args, **kw).as_text(debug_info=True)
+    assert (len(w.outvars), "scu.hop" in text) == ((16, False) if hierarchy is None else (18, True))
