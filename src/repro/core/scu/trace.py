@@ -1444,7 +1444,8 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
     :func:`_pack_tables`'s SCU shape.  Under a hierarchy ``hop`` is its
     latencies and the table has a tenth column, the hop classes.  Under jax
     this is the body of :func:`_jitted_execute`, so the tables are its
-    inputs."""
+    inputs.  The final state also holds ``out``, the host's whole result
+    packed after the loop (:func:`_pack_result`)."""
     n, length, _ = tab.shape
     mem = (tab[:, :, 0] == T_MEM) | (tab[:, :, 0] == T_POLL)
     row_bank = addr_bank[xp.where(mem, tab[:, :, 3], 0)]
@@ -1498,7 +1499,7 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
             state["fetch"] = np.zeros(n, dtype=bool)
             state = _cycle_step(t, state)
             state["iters"] = state["iters"] + 1
-        return state
+        return dict(state, out=_pack_result(np, state))
     import jax
 
     def cond(s):
@@ -1512,7 +1513,33 @@ def _execute(xp, tab, addr_bank, max_cycles, *, n_banks: int, tas_cycles: int,
         s["iters"] = s["iters"] + 1
         return s
 
-    return jax.lax.while_loop(cond, body, state)
+    state = jax.lax.while_loop(cond, body, state)
+    return dict(state, out=_pack_result(xp, state))
+
+
+def _pack_result(xp, s):
+    """The final state's result as one int32 vector, so the host reads it
+    in one transfer: ``cycle``, ``iters`` and ``live`` (0 or 1), the
+    counters row by row, then per lane ``conflicts`` and ``fin``, the TCDM
+    words, and under a hierarchy per lane ``remote``.  :func:`_unpack_result`
+    slices it back."""
+    head = xp.stack([xp.asarray(s[k], dtype=xp.int32) for k in ("cycle", "iters", "live")])
+    parts = [head, s["cnt"].reshape(-1), s["conflicts"], s["fin"], s["tcdm"]]
+    if "remote" in s:
+        parts.append(s["remote"])
+    # numpy widens some rows to int64; the int32 bound of run_traces_xp
+    # holds every count, so both namespaces return the same int32 rows
+    return xp.concatenate(parts).astype(xp.int32)
+
+
+def _unpack_result(out: np.ndarray, n: int, words: int):
+    """Slice :func:`_pack_result`'s host copy for ``n`` lanes and ``words``
+    TCDM words, in its order: ``cycle``, ``iters`` and ``live`` as ints,
+    the counter rows, the conflicts and retire cycles, the TCDM words and
+    the remote grants (empty on a flat cluster)."""
+    rows = len(_COUNTERS) * n
+    cnt, conflicts, fin, tcdm, remote = np.split(out[3:], np.cumsum([rows, n, n, words]))
+    return (*map(int, out[:3]), cnt.reshape(len(_COUNTERS), n), conflicts, fin, tcdm, remote)
 
 
 @functools.cache
@@ -1575,11 +1602,14 @@ def run_traces_xp(
     and account phases carry the named scopes ``scu.issue``,
     ``scu.decode``, ``scu.grant`` and ``scu.account``, the SCU phase of a
     table with SCU rows ``scu.sync``, and the jump ``scu.jump``.  The
-    readback counts the job's SCU transactions as ``scu.sync_ops``, its
-    loop iterations as ``scu.loop_iterations`` and its granted TCDM
-    accesses to banks outside the lane's tile as ``scu.remote_accesses``
-    (0 on a flat cluster), the last two fetched with the cycle count and
-    the finish flag in one transfer.
+    readback fetches the whole result in one transfer: the compiled
+    program packs it after its loop into one int32 vector
+    (:func:`_pack_result`), and the host slices its copy by offsets it
+    knows from the lane count, the TCDM words and the hierarchy, and sums
+    over lanes there.  It counts the job's SCU transactions as
+    ``scu.sync_ops``, its loop iterations as ``scu.loop_iterations`` and
+    its granted TCDM accesses to banks outside the lane's tile as
+    ``scu.remote_accesses`` (0 on a flat cluster).
 
     One loop iteration is one simulated cycle and a jump (:func:`_quiet_jump`)
     over the quiet cycles after it: while no lane can act -- every live
@@ -1637,26 +1667,22 @@ def run_traces_xp(
             if not is_np:
                 jax.block_until_ready(state)
         with obs.span("scu.readback"):
-            scalars = (state["cycle"], state["iters"], state["live"])
-            if hop:
-                scalars += (state["remote"].sum(),)
-            cycles, iters, live, *remote = map(int, scalars if is_np else jax.device_get(scalars))
+            out = state["out"] if is_np else jax.device_get(state["out"])
+            cycles, iters, live, cnt, conflicts, fin, tcdm, remote = _unpack_result(
+                out, tab.shape[0], len(addr_bank))
             obs.count("scu.loop_iterations", iters)
-            obs.count("scu.remote_accesses", sum(remote))
+            obs.count("scu.remote_accesses", int(remote.sum()))
             if live:
                 raise RuntimeError(f"traced run did not finish within {max_cycles} cycles")
 
-            counters = {
-                name: np.asarray(state["cnt"][i])
-                for i, name in enumerate(_COUNTERS)
-            }
+            counters = dict(zip(_COUNTERS, cnt))
             obs.count("scu.sync_ops", int(counters["scu_accesses"].sum()))
             return {
                 "cycles": cycles,
                 "counters": counters,
-                "bank_conflicts": int(np.asarray(state["conflicts"]).sum()),
-                "finished_at": np.asarray(state["fin"]),
-                "tcdm": dict(zip(addrs_np.tolist(), np.asarray(state["tcdm"]).tolist())),
+                "bank_conflicts": int(conflicts.sum()),
+                "finished_at": fin,
+                "tcdm": dict(zip(addrs_np.tolist(), tcdm.tolist())),
             }
 
 

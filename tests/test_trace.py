@@ -462,20 +462,92 @@ def test_loop_body_has_no_gather_or_scatter(policy):
     assert not [p for p in seen if p.startswith(("gather", "scatter"))]
 
 
+def _parity_job(job):
+    """A fresh job for the numpy-vs-jax parity test: its programs and the
+    executor's keywords.  The SCU mutex has no TCDM word; ``hierarchy``
+    is the MemPool-style tree barrier of :data:`_HIER_JOBS`."""
+    if job in _TRACE_SETS:
+        n = 4 if job == "tcdm" else 8
+        return _TRACE_SETS[job](n), dict(n_banks=2 * n)
+    fb = _scu_job(("mutex", "scu", 10, 0), 4) if job == "scu" else _HIER_JOBS["tree"](_HIER)
+    cl = fb.config.cluster
+    return fb.config.programs, dict(n_banks=cl.n_banks, hierarchy=cl.hierarchy)
+
+
 @pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
-@pytest.mark.parametrize("traces", tuple(_TRACE_SETS))
+@pytest.mark.parametrize("traces", (*_TRACE_SETS, "scu", "hierarchy"))
 def test_run_traces_jax_matches_numpy(traces):
+    """Both namespaces return the same result, field for field and type
+    for type: ints for the cycles and conflicts, int32 rows for the
+    counters and retire cycles, and the TCDM words as a dict of ints
+    (empty for a table with no TCDM word)."""
     from repro.core.scu.trace import run_traces_jax
 
-    n = 4 if traces == "tcdm" else 8
-    ref = run_traces_xp(_TRACE_SETS[traces](n), n_banks=2 * n)
-    got = run_traces_jax(_TRACE_SETS[traces](n), n_banks=2 * n)
+    progs, kw = _parity_job(traces)
+    ref = run_traces_xp(progs, **kw)
+    got = run_traces_jax(_parity_job(traces)[0], **kw)
+    assert got.keys() == ref.keys()
+    for res in (ref, got):
+        assert type(res["cycles"]) is int and type(res["bank_conflicts"]) is int
+        for row in (*res["counters"].values(), res["finished_at"]):
+            assert type(row) is np.ndarray and row.dtype == np.int32 and row.shape == (len(progs),)
+        assert type(res["tcdm"]) is dict
+        assert all(type(k) is int and type(v) is int for k, v in res["tcdm"].items())
+        assert (res["tcdm"] == {}) == (traces == "scu")
     assert got["cycles"] == ref["cycles"]
     assert got["bank_conflicts"] == ref["bank_conflicts"]
+    assert list(got["counters"]) == list(ref["counters"]) == list(_COUNTERS)
     for name in _COUNTERS:
         assert got["counters"][name].tolist() == ref["counters"][name].tolist()
     assert got["finished_at"].tolist() == ref["finished_at"].tolist()
     assert got["tcdm"] == ref["tcdm"]
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
+@pytest.mark.parametrize("traces", ["tcdm", "hierarchy"], ids=["flat", "hierarchy"])
+def test_jax_executor_reads_its_result_back_in_one_transfer(traces, monkeypatch):
+    """The jax executor fetches its whole result in one explicit
+    ``jax.device_get`` inside ``scu.readback``, and turns no device array
+    into host data any other way.  On the CPU the transfer guard does not
+    fire, so the array class's host conversions raise outside
+    ``device_get`` for the call."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import obs
+    from repro.core.scu.trace import run_traces_jax
+
+    progs, kw = _parity_job(traces)
+    run_traces_jax(_parity_job(traces)[0], **kw)  # compile outside the guarded call
+    fetches, inside = [], [False]
+    device_get = jax.device_get
+
+    def counted_get(x):
+        fetches.append(time.perf_counter_ns())
+        inside[0] = True
+        try:
+            return device_get(x)
+        finally:
+            inside[0] = False
+
+    array_cls = type(jnp.zeros(1))
+    for name in ("__array__", "__buffer__", "__int__", "__index__", "__bool__", "__float__",
+                 "tolist", "item"):
+        conversion = getattr(array_cls, name)
+
+        def guarded(self, *a, _conversion=conversion, _name=name, **k):
+            if not inside[0]:
+                raise AssertionError(f"implicit device-to-host transfer through {_name}")
+            return _conversion(self, *a, **k)
+
+        monkeypatch.setattr(array_cls, name, guarded)
+    monkeypatch.setattr(jax, "device_get", counted_get)
+    t0 = time.perf_counter()
+    res = run_traces_jax(progs, **kw)
+    monkeypatch.undo()
+    (readback,) = [e for e in obs.events(t0, time.perf_counter()) if e.name == "scu.readback"]
+    assert len(fetches) == 1 and readback.start_ns <= fetches[0] <= readback.end_ns
+    assert res["cycles"] > 0
 
 
 @pytest.mark.parametrize("xp_name", ["numpy", "jax"])
